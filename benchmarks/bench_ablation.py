@@ -10,6 +10,7 @@ from __future__ import annotations
 import json
 from pathlib import Path
 
+from repro.compile_cache import use_compile_cache
 from repro.core.simulator import SimParams, make_batch, run_sweep
 from repro.core.traffic import TRAFFIC_SPECS
 
@@ -51,4 +52,5 @@ def main():
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

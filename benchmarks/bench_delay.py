@@ -25,6 +25,7 @@ import json
 import time
 from pathlib import Path
 
+from repro.compile_cache import use_compile_cache
 from repro.core import simulator as S
 from repro.core.traffic import TRAFFIC_SPECS
 
@@ -147,4 +148,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

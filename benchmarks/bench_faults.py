@@ -47,6 +47,7 @@ from pathlib import Path
 import numpy as np
 
 from benchmarks import baseline_gate as BG
+from repro.compile_cache import use_compile_cache
 from repro.core import simulator as S
 from repro.core.simulator import SimParams, make_batch, run_sweep
 from repro.core.topology import FBSite
@@ -279,4 +280,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

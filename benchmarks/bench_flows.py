@@ -49,6 +49,7 @@ from pathlib import Path
 import numpy as np
 
 from benchmarks import baseline_gate as BG
+from repro.compile_cache import use_compile_cache
 from repro.core import simulator as S
 from repro.core import workloads
 from repro.core.simulator import SimParams, make_batch, run_sweep
@@ -284,4 +285,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

@@ -28,6 +28,7 @@ import json
 import time
 from pathlib import Path
 
+from repro.compile_cache import use_compile_cache
 from repro.core import simulator as S
 from repro.core.topology import FBSite, all_designs
 from repro.core.traffic import TRAFFIC_SPECS
@@ -161,4 +162,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

@@ -65,6 +65,7 @@ import time
 from pathlib import Path
 
 from benchmarks import baseline_gate as BG
+from repro.compile_cache import use_compile_cache
 from repro.core import checkpoint as CK
 from repro.core import simulator as S
 from repro.core.simulator import (CheckpointSpec, SimParams, grid_runs,
@@ -508,4 +509,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

@@ -2,11 +2,15 @@
 
 Prints ``name,us_per_call,derived`` CSV. ``us_per_call`` is the wall time
 of producing that figure's numbers (simulation/analysis cost); ``derived``
-carries the figure's headline metrics next to the paper's claims.
+carries the figure's headline metrics next to the paper's claims. A
+benchmark that raises still gets its row (``ERROR: ...``), the others
+still run, and the run then exits nonzero naming every failed row.
 
   PYTHONPATH=src python -m benchmarks.run
 """
 from __future__ import annotations
+
+from repro.compile_cache import use_compile_cache
 
 
 def main() -> None:
@@ -42,7 +46,12 @@ def main() -> None:
     for name, us, derived in rows:
         d = str(derived).replace(",", ";")
         print(f"{name},{us:.1f},{d}")
+    failed = [name for name, _, derived in rows
+              if str(derived).startswith("ERROR")]
+    if failed:
+        raise SystemExit(f"failed benchmarks: {', '.join(failed)}")
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

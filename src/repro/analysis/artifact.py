@@ -252,7 +252,7 @@ def _audit_sweep_case(unit: dict, case: dict, art: dict, mode: str,
     live = jnp.ones((length,), bool)
     ci = jnp.asarray(0, jnp.int32) if validate else None
 
-    runner = S._sweep_runner()
+    runner = S._runner_for(scen)
     comp = runner.lower(hull, scen, state, length, live, fold, guard,
                         ci, tol, validate).compile()
     txt = comp.as_text()
@@ -271,7 +271,7 @@ def _audit_sweep_case(unit: dict, case: dict, art: dict, mode: str,
     }
 
     path, where = unit.get("file", ARTIFACT_RELPATH), \
-        f"sweep_chunk[{case.get('tag', '?')}{'+validate' if validate else ''}]"
+        f"sweep_chunk[{case.get('tag', '?')}]"
     findings = []
     findings += check_collectives_text(
         txt, unit.get("collectives_allowed", []), path, where)

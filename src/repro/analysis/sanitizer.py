@@ -42,7 +42,10 @@ import jax
 from repro.core import simulator
 from repro.core.topology import full_site_tag
 
-_COMPILE_RE = re.compile(r"Compiling (\S+) with global shapes")
+#: "Compiling <name> with global shapes ..."; JAX 0.9 writes the name
+#: as ``jit(<name>)``, older releases bare — both yield <name>
+_COMPILE_RE = re.compile(
+    r"Compiling (?:jit\((\S+?)\)|(\S+)) with global shapes")
 #: the logger jax.log_compiles routes "Compiling <name> ..." through
 _COMPILE_LOGGER = "jax._src.interpreters.pxla"
 
@@ -67,7 +70,7 @@ class CompileWatcher(logging.Handler):
     def emit(self, record):
         m = _COMPILE_RE.search(record.getMessage())
         if m:
-            self.events.append(m.group(1))
+            self.events.append(m.group(1) or m.group(2))
 
     def compiles_of(self, name: str) -> int:
         return sum(1 for e in self.events if e == name)

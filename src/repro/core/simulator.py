@@ -167,7 +167,11 @@ Scenario knobs, sim state and fold buffers are placed with a
 device count are padded with copies of scenario 0 and the pad rows
 dropped before finalization — scenarios are independent vmap lanes, so
 padding and sharding are bit-inert for every real scenario's metrics
-(tests/test_sharding.py pins this on 4 fake devices).
+(tests/test_sharding.py pins this on 4 fake devices). The sharded chunk
+program is a ``shard_map`` over that axis: each device runs the plain
+chunk program on its own rows, so no collective is ever inserted, and
+the Pallas switch kernel (which Mosaic cannot auto-partition) runs
+per device.
 
 ``run_sweep_planned`` pipelines its hull buckets: every bucket's chunk
 programs are DISPATCHED first (largest padded cost first, the planner's
@@ -1146,7 +1150,11 @@ def make_sim_step(hull: FBSite):
         fc_down_q = fc_down_q - fserve
         stranded = jnp.where(~fc_active, fc_down_q, 0.0)
         mig = jnp.minimum(jnp.sum(stranded), scen.fc_ring)
-        mfrac = mig / jnp.maximum(jnp.sum(stranded), 1e-9)
+        # <= 1 under exact division; clamped because a TPU's is not (an
+        # ulp over 1 moves more than is stranded and leaves a negative
+        # backlog, see kernels/ref.py) — a no-op on the CPU
+        mfrac = jnp.minimum(mig / jnp.maximum(jnp.sum(stranded), 1e-9),
+                            1.0)
         fc_down_q = fc_down_q - stranded * mfrac
         fc_down_q = fc_down_q.at[0, :].add(
             jnp.sum(stranded * mfrac, axis=0))
@@ -1191,7 +1199,8 @@ def make_sim_step(hull: FBSite):
         stranded_d = jnp.where(~down_act, dq, 0.0)               # (NCL,P,RPC)
         tot_str = jnp.sum(stranded_d, axis=(1, 2))               # (NCL,)
         migd = jnp.minimum(tot_str, scen.csw_ring)
-        dfrac = (migd / jnp.maximum(tot_str, 1e-9))[:, None, None]
+        dfrac = jnp.minimum(migd / jnp.maximum(tot_str, 1e-9),
+                            1.0)[:, None, None]   # clamped as mfrac
         moved = stranded_d * dfrac
         dq = dq - moved
         dq = dq.at[:, 0, :].add(jnp.sum(moved, axis=1))
@@ -1644,13 +1653,48 @@ def _sweep_chunk_impl(site: FBSite, scen: Scenario, state: SimState,
 
 
 @functools.lru_cache(maxsize=None)
-def _sweep_runner():
-    # carry donation is a no-op (warning) on CPU; enable it only where
-    # the backend supports buffer donation
+def _sweep_runner(mesh=None):
+    """The jitted chunk program. With a ``mesh`` (the scenario-axis
+    layout of ``_scen_sharding``) it is a ``shard_map`` of the same
+    program over the mesh's ``scen`` axis: every device steps its own
+    rows, and the replicated operands (live mask, chunk index, tol)
+    reach all of them. Scenarios are independent lanes, so the sharded
+    program needs no collective."""
+    # accelerators donate the carry so each chunk updates it in place;
+    # the CPU keeps it (the artifact audit's CPU budgets are measured
+    # so, and its forced-donation probe checks the aliasing there)
     kw = {} if jax.default_backend() == "cpu" \
         else {"donate_argnames": ("state", "fold")}
-    return jax.jit(_sweep_chunk_impl,
-                   static_argnames=("site", "length", "validate"), **kw)
+    impl = _sweep_chunk_impl
+    if mesh is not None:
+        from jax.sharding import PartitionSpec
+        lane, rep = PartitionSpec("scen"), PartitionSpec()
+
+        @functools.wraps(_sweep_chunk_impl)
+        def impl(site, scen, state, length, live, fold, guard=None,
+                 chunk_idx=None, tol=None, validate=False):
+            def body(scen, state, live, fold, guard, chunk_idx, tol):
+                return _sweep_chunk_impl(site, scen, state, length, live,
+                                         fold, guard, chunk_idx, tol,
+                                         validate)
+            return jax.shard_map(
+                body, mesh=mesh,
+                in_specs=(lane, lane, rep, lane, lane, rep, rep),
+                out_specs=lane, check_vma=False)(
+                    scen, state, live, fold, guard, chunk_idx, tol)
+    return jax.jit(impl, static_argnames=("site", "length", "validate"),
+                   **kw)
+
+
+def _runner_for(scen):
+    """The chunk program for operands laid out as ``scen`` is: the
+    shard_mapped one when the scenario axis is spread over a mesh of
+    several devices, else the plain one."""
+    from jax.sharding import NamedSharding
+    sh = getattr(jax.tree.leaves(scen)[0], "sharding", None)
+    if isinstance(sh, NamedSharding) and sh.mesh.size > 1:
+        return _sweep_runner(sh.mesh)
+    return _sweep_runner()
 
 
 @functools.lru_cache(maxsize=None)
@@ -1760,21 +1804,21 @@ def _dispatch_chunks(batch: ScenarioBatch, scen: Scenario, state: SimState,
     and resume reuses it, so the live-tick masks line up exactly.
 
     Checkpointing (``checkpoint`` set; device fold only) snapshots the
-    full carry at every ``every_chunks`` boundary, DEFERRED BY ONE
-    CHUNK: the snapshot taken at boundary ``ci`` is written only after
-    chunk ``ci`` (the next one) has been dispatched, so the device
-    always has work enqueued while the host fetches and serializes —
-    cadenced snapshots throttle but never serialize the async pipeline.
+    full carry at every ``every_chunks`` boundary. The carry is FETCHED
+    at its boundary ``ci``, before chunk ``ci`` is dispatched: that
+    dispatch donates the carry's buffers on an accelerator, so only the
+    host copy outlives it. The file WRITE is deferred by one chunk, so
+    the device runs chunk ``ci`` while the host serializes and writes.
     The final boundary is never snapshotted (the run is finished, not
     resumable, there).
     """
     global HOST_TRANSFER_COUNT
-    runner = _sweep_runner()
+    runner = _runner_for(scen)
     hull = batch.hull
     acc64 = None
     done = start_chunk * chunk
     ci = start_chunk
-    pending_snap = None
+    pending_write = None
     while done < n_ticks:
         if CHUNK_HOOK is not None:
             CHUNK_HOOK(ci)
@@ -1797,15 +1841,15 @@ def _dispatch_chunks(batch: ScenarioBatch, scen: Scenario, state: SimState,
             state = state._replace(
                 acc=jax.tree.map(jnp.zeros_like, state.acc))
         done += chunk
-        if pending_snap is not None:
-            _snapshot_sweep(checkpoint, batch, *pending_snap,
-                            n_ticks=n_ticks, chunk=chunk,
-                            validate=validate, tol=tol, n_real=n_real,
-                            plan_meta=plan_meta)
-            pending_snap = None
+        if pending_write is not None:
+            pending_write()
+            pending_write = None
         if (checkpoint is not None and done < n_ticks
                 and ci % checkpoint.every_chunks == 0):
-            pending_snap = (ci, state, dev_fold, guard)
+            pending_write = _snapshot_sweep(
+                checkpoint, batch, ci, state, dev_fold, guard,
+                n_ticks=n_ticks, chunk=chunk, validate=validate, tol=tol,
+                n_real=n_real, plan_meta=plan_meta)
     return _PendingSweep(batch=batch, n_ticks=n_ticks, fold=dev_fold,
                          acc64=acc64, state=state, n_real=n_real,
                          guard=guard)
@@ -1898,7 +1942,9 @@ def _snapshot_sweep(spec: CheckpointSpec, batch: ScenarioBatch,
                     ci: int, state: SimState, dev_fold, guard, *,
                     n_ticks: int, chunk: int, validate: bool, tol,
                     n_real: int, plan_meta: dict | None = None):
-    """Write one checkpoint of a running sweep's full carry.
+    """Fetch one checkpoint of a running sweep's full carry now, and
+    return the zero-argument function that writes it (and returns its
+    path), so the caller can overlap the write with the next chunk.
 
     THE registered checkpoint fetch (an RL003 blessed transfer): ONE
     explicit ``jax.device_get`` of the whole carry — every SimState
@@ -1946,9 +1992,12 @@ def _snapshot_sweep(spec: CheckpointSpec, batch: ScenarioBatch,
         "seeds": [int(s) for s in batch.seeds],
         "plan": plan_meta, "tag": spec.tag,
     }
-    path = _ckpt.write_checkpoint(spec.path_for(ci), meta, arrays)
-    _ckpt.prune(spec)
-    return path
+
+    def write():
+        path = _ckpt.write_checkpoint(spec.path_for(ci), meta, arrays)
+        _ckpt.prune(spec)
+        return path
+    return write
 
 
 def resume_sweep(path, *, return_state: bool = False,
@@ -2274,7 +2323,7 @@ def run_sweep_planned(runs: Sequence[tuple[SimParams, int]], n_ticks: int,
                 n_ticks=n_ticks,
                 chunk=max(1, min(chunk_ticks, n_ticks)),
                 validate=validate, tol=tol, n_real=len(batch),
-                plan_meta=bucket_plan_meta(k)))
+                plan_meta=bucket_plan_meta(k))())
         except Exception:                  # noqa: BLE001 — best effort
             return None
 

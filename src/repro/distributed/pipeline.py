@@ -14,8 +14,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
-
 
 def pipeline_apply(mesh, stage_axis: str, n_stages: int, layer_fn,
                    stacked_params, x, n_micro: int):
@@ -63,7 +61,7 @@ def pipeline_apply(mesh, stage_axis: str, n_stages: int, layer_fn,
         return jax.lax.psum(outs, stage_axis)
 
     xm = x.reshape(n_micro, mb, *x.shape[1:])
-    out = shard_map(
+    out = jax.shard_map(
         stage_body, mesh=mesh,
         in_specs=(P(stage_axis), P()),      # params sharded by stage
         out_specs=P(),                      # every stage returns; last wins

@@ -18,7 +18,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import tpu_compiler_params
 
 NEG_INF = -1e30
 
@@ -110,7 +109,7 @@ def flash_attention(q, k, v, *, causal=True, swa_window=0,
             pltpu.VMEM((bq, 1), jnp.float32),     # running denom
             pltpu.VMEM((bq, dv), jnp.float32),    # output accumulator
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

@@ -17,11 +17,16 @@ tile of switches, one tick of
       the oracle-checked feed of the simulator's in-scan packet-delay
       histograms.
 
-All switches in a tile advance in one VPU-wide vector step; queues are
-laid out (S, L*K) so the tile stays 2-D (lane-friendly) and is reshaped
-to (bs, L, K) inside the kernel. cap/hi/lo ride in as per-switch operand
-columns rather than compile-time constants so per-scenario values (the
-batched sweep engine's array-valued knobs) trace through one compile.
+All switches in a tile advance in one VPU-wide vector step. Every
+operand is a 2-D (S, ·) array: the K queue components ride in as K
+separate (S, L) planes and the arrival vector as K (S, 1) columns, so
+the kernel body never reshapes and Mosaic sees only rank-2 layouts.
+Masks are float32 0/1 values reduced with max ("all" is "no 1 in the
+complement") and the min-backlog tie-break is a first-index pick (iota
++ min), because Mosaic lowers neither bool reductions nor cumsum.
+cap/hi/lo ride in as per-switch operand columns rather than
+compile-time constants so per-scenario values (the batched sweep
+engine's array-valued knobs) trace through one compile.
 
 The switch axis is padded up to the block size and outputs sliced back,
 so odd-sized tiers (e.g. the 16-CSW tier under a 128 block) work.
@@ -40,36 +45,45 @@ from jax.experimental import pallas as pl
 BIG = 1e30
 
 
-def _kernel(q_ref, stage_ref, drain_ref, valid_ref, arr_ref, cap_ref,
-            hi_ref, lo_ref, qo_ref, srv_ref, hi_o_ref, lo_o_ref,
-            drop_ref, wait_ref, m1_ref, m2_ref, *, n_links: int,
-            n_comp: int, serve_rate: float):
-    L, K = n_links, n_comp
-    bs = q_ref.shape[0]
-    q = q_ref[...].reshape(bs, L, K)
+def _kernel(*refs, n_comp: int, serve_rate: float):
+    K = n_comp
+    q_refs = refs[:K]                               # K x (bs, L)
+    stage_ref, drain_ref, valid_ref = refs[K:K + 3]
+    arr_refs = refs[K + 3:2 * K + 3]                # K x (bs, 1)
+    cap_ref, hi_ref, lo_ref = refs[2 * K + 3:2 * K + 6]
+    qo_refs = refs[2 * K + 6:3 * K + 6]
+    srv_refs = refs[3 * K + 6:4 * K + 6]
+    hi_o_ref, lo_o_ref, drop_ref, wait_ref, m1_ref, m2_ref = \
+        refs[4 * K + 6:]
+
+    qs = [r[...] for r in q_refs]
     stage = stage_ref[...]                          # (bs, 1) int32
-    drain = drain_ref[...] != 0                     # (bs, 1)
-    link_valid = valid_ref[...] != 0                # (bs, L) per-link
-    arr = arr_ref[...]                              # (bs, K)
+    link_valid = valid_ref[...].astype(jnp.float32)  # (bs, L) 0/1
+    arrs = [r[...] for r in arr_refs]
     cap = cap_ref[...]                              # (bs, 1)
+    bs, L = link_valid.shape
     # a switch is live iff any of its ports is valid
-    vswitch = jnp.any(link_valid, axis=1, keepdims=True)   # (bs, 1)
+    vswitch = jnp.max(link_valid, axis=1, keepdims=True) > 0.0  # (bs, 1)
 
     idx = jax.lax.broadcasted_iota(jnp.int32, (bs, L), 1)
-    act = (idx < stage) & link_valid
-    top = idx == stage - 1
-    usable = act & ~(drain & top & (stage > 1))
-    qtot = jnp.sum(q, axis=2)                       # (bs, L)
+    act = jnp.where(idx < stage, link_valid, 0.0)   # (bs, L) 0/1
+    # a draining top port (stage > 1) serves but accepts nothing
+    drain_top = jnp.where((drain_ref[...] != 0) & (stage > 1), 1.0, 0.0)
+    usable = jnp.where(idx == stage - 1, act * (1.0 - drain_top), act)
+    qtot = functools.reduce(jnp.add, qs)            # (bs, L)
 
-    # (1) min-backlog selection among usable ports, ties to lowest index
-    masked = jnp.where(usable, qtot, BIG)
+    # (1) min-backlog selection among usable ports, ties to the lowest
+    # index: the first lane holding the minimum
+    masked = jnp.where(usable > 0.0, qtot, BIG)
     mn = jnp.min(masked, axis=1, keepdims=True)
-    pick = masked == mn
-    pick &= jnp.cumsum(pick.astype(jnp.int32), axis=1) == 1
+    idx_f = idx.astype(jnp.float32)
+    first = jnp.min(jnp.where(masked == mn, idx_f, float(L)), axis=1,
+                    keepdims=True)
+    pick = jnp.where(idx_f == first, 1.0, 0.0)      # (bs, L) one-hot
     # per-link faults can leave a live switch with no usable port: keep
     # the BIG sentinel out of the taps and collapse the room to 0 so
     # the whole arrival drops (matches ref.switch_step_ref)
-    has_usable = jnp.any(usable, axis=1, keepdims=True)
+    has_usable = jnp.max(usable, axis=1, keepdims=True) > 0.0
     mn0 = jnp.where(has_usable, mn, 0.0)
 
     # (5a) backlog-age of the pick: what an arrival queues behind
@@ -79,21 +93,21 @@ def _kernel(q_ref, stage_ref, drain_ref, valid_ref, arr_ref, cap_ref,
     # arrival at a switch with no valid port left (all transceivers
     # hard-faulted) is a counted drop, not a silent loss (padded
     # switches receive zero arrivals, so they still report 0)
-    add_tot = jnp.sum(arr, axis=1, keepdims=True)   # (bs, 1)
+    add_tot = functools.reduce(jnp.add, arrs)       # (bs, 1)
     room = jnp.where(has_usable, jnp.maximum(cap - mn0, 0.0), 0.0)
     scale = jnp.minimum(1.0, room / jnp.maximum(add_tot, 1e-9))
     drop_ref[...] = jnp.where(vswitch, add_tot * (1.0 - scale), add_tot)
-    q = q + pick.astype(q.dtype)[..., None] \
-        * (arr * scale)[:, None, :]
+    qs = [q + pick * (a * scale) for q, a in zip(qs, arrs)]
 
     # (3) serve up to serve_rate pkts per active port, proportional
-    qtot = jnp.sum(q, axis=2)
+    qtot = functools.reduce(jnp.add, qs)
     serve_tot = jnp.minimum(qtot, serve_rate) * act
-    frac = serve_tot / jnp.maximum(qtot, 1e-9)
-    served = q * frac[..., None]
-    q = q - served
-    qo_ref[...] = q.reshape(bs, L * K)
-    srv_ref[...] = served.reshape(bs, L * K)
+    # clamped to 1: TPU division is not correctly rounded (see ref.py)
+    frac = jnp.minimum(serve_tot / jnp.maximum(qtot, 1e-9), 1.0)
+    for q, qo_ref, srv_ref in zip(qs, qo_refs, srv_refs):
+        served = q * frac
+        qo_ref[...] = q - served
+        srv_ref[...] = served
 
     # (4) watermark triggers on post-serve backlogs; invalid switches
     # never trigger (lo would otherwise fire vacuously on act==empty)
@@ -105,22 +119,26 @@ def _kernel(q_ref, stage_ref, drain_ref, valid_ref, arr_ref, cap_ref,
     m2_ref[...] = jnp.where(vswitch,
                             jnp.sum(qpost * qpost, axis=1, keepdims=True),
                             0.0)
-    hi_o_ref[...] = jnp.any((qpost > hi_ref[...] * cap) & act, axis=1,
-                            keepdims=True).astype(jnp.int32)
-    lo_o_ref[...] = (jnp.all(jnp.where(act, qpost < lo_ref[...] * cap,
-                                       True), axis=1, keepdims=True)
-                     & vswitch).astype(jnp.int32)
+    over_hi = jnp.where(qpost > hi_ref[...] * cap, act, 0.0)
+    hi_o_ref[...] = jnp.where(jnp.max(over_hi, axis=1, keepdims=True) > 0.0,
+                              1, 0).astype(jnp.int32)
+    under_lo = jnp.where((act > 0.0) & ~(qpost < lo_ref[...] * cap),
+                         1.0, 0.0)
+    lo_o_ref[...] = jnp.where(
+        (jnp.max(under_lo, axis=1, keepdims=True) == 0.0) & vswitch,
+        1, 0).astype(jnp.int32)
 
 
 def switch_step(queues, stage, arrivals, draining=None, *, valid=None,
                 cap=20.0, hi=0.75, lo=0.22, serve_rate=1.0, block_s=128,
-                interpret=True):
+                interpret=False):
     """queues (S, L, K) or (S, L); stage (S,) int32; arrivals (S, K) or
     (S,); draining (S,) bool; valid (S,) bool padding mask (invalid
     switches are inert) or (S, L) bool per-link usability mask (the
     fault-injection axis: dead ports on live switches). Same contract
     as ref.switch_step_ref: returns (new_queues, served, hi_trig,
-    lo_trig, dropped, enq_wait, occ_m1, occ_m2)."""
+    lo_trig, dropped, enq_wait, occ_m1, occ_m2). ``interpret=True``
+    runs the body in the Pallas interpreter (any backend)."""
     squeeze = queues.ndim == 2
     if squeeze:
         queues = queues[..., None]
@@ -140,7 +158,7 @@ def switch_step(queues, stage, arrivals, draining=None, *, valid=None,
     Sp = _round_up(S, bs)
     pad = Sp - S
     f32 = queues.dtype
-    qp = jnp.pad(queues, ((0, pad), (0, 0), (0, 0))).reshape(Sp, L * K)
+    qp = jnp.pad(queues, ((0, pad), (0, 0), (0, 0)))
     stage_p = jnp.pad(stage, (0, pad), constant_values=1)[:, None]
     drain_p = jnp.pad(draining, (0, pad)).astype(jnp.int32)[:, None]
     valid_p = jnp.pad(link_valid, ((0, pad), (0, 0))).astype(jnp.int32)
@@ -152,33 +170,25 @@ def switch_step(queues, stage, arrivals, draining=None, *, valid=None,
             return jnp.full((Sp, 1), v)
         return jnp.pad(v.reshape(-1), (0, pad))[:, None]
 
-    kern = functools.partial(_kernel, n_links=L, n_comp=K,
-                             serve_rate=float(serve_rate))
-    spec_lk = pl.BlockSpec((bs, L * K), lambda i: (i, 0))
+    kern = functools.partial(_kernel, n_comp=K, serve_rate=float(serve_rate))
     spec_1 = pl.BlockSpec((bs, 1), lambda i: (i, 0))
-    spec_k = pl.BlockSpec((bs, K), lambda i: (i, 0))
     spec_l = pl.BlockSpec((bs, L), lambda i: (i, 0))
-    qo, srv, hi_t, lo_t, drop, wait, m1, m2 = pl.pallas_call(
+    plane = jax.ShapeDtypeStruct((Sp, L), f32)
+    column = jax.ShapeDtypeStruct((Sp, 1), f32)
+    flag = jax.ShapeDtypeStruct((Sp, 1), jnp.int32)
+    outs = pl.pallas_call(
         kern,
         grid=(Sp // bs,),
-        in_specs=[spec_lk, spec_1, spec_1, spec_l, spec_k, spec_1, spec_1,
-                  spec_1],
-        out_specs=[spec_lk, spec_lk, spec_1, spec_1, spec_1, spec_1,
-                   spec_1, spec_1],
-        out_shape=[
-            jax.ShapeDtypeStruct((Sp, L * K), f32),
-            jax.ShapeDtypeStruct((Sp, L * K), f32),
-            jax.ShapeDtypeStruct((Sp, 1), jnp.int32),
-            jax.ShapeDtypeStruct((Sp, 1), jnp.int32),
-            jax.ShapeDtypeStruct((Sp, 1), f32),
-            jax.ShapeDtypeStruct((Sp, 1), f32),
-            jax.ShapeDtypeStruct((Sp, 1), f32),
-            jax.ShapeDtypeStruct((Sp, 1), f32),
-        ],
+        in_specs=[spec_l] * K + [spec_1, spec_1, spec_l] + [spec_1] * K
+        + [spec_1] * 3,
+        out_specs=[spec_l] * (2 * K) + [spec_1] * 6,
+        out_shape=[plane] * (2 * K) + [flag, flag] + [column] * 4,
         interpret=interpret,
-    )(qp, stage_p, drain_p, valid_p, arr_p, col(cap), col(hi), col(lo))
-    qo = qo[:S].reshape(S, L, K)
-    srv = srv[:S].reshape(S, L, K)
+    )(*[qp[..., k] for k in range(K)], stage_p, drain_p, valid_p,
+      *[arr_p[:, k:k + 1] for k in range(K)], col(cap), col(hi), col(lo))
+    qo = jnp.stack(outs[:K], axis=-1)[:S]
+    srv = jnp.stack(outs[K:2 * K], axis=-1)[:S]
+    hi_t, lo_t, drop, wait, m1, m2 = outs[2 * K:]
     if squeeze:
         qo, srv = qo[..., 0], srv[..., 0]
     return (qo, srv, hi_t[:S, 0], lo_t[:S, 0], drop[:S, 0], wait[:S, 0],

@@ -1,9 +1,10 @@
 """Jitted dispatch wrappers for the Pallas kernels.
 
-On TPU the Pallas body compiles natively; on CPU (this container) the
-default is the pure-jnp reference path so jitted model code stays
-analyzable/compilable, with ``use_pallas=True`` running the kernels in
-interpret mode (the correctness path exercised by tests/test_kernels.py).
+On TPU the Pallas body compiles natively (``interpret=False``); on
+other backends the default is the pure-jnp reference path so jitted
+code stays analyzable/compilable, with ``use_pallas=True`` running the
+kernels in interpret mode (the correctness path exercised by
+tests/test_kernels.py).
 """
 from __future__ import annotations
 

@@ -146,7 +146,12 @@ def switch_step_ref(queues, stage, arrivals, draining=None, *,
     # active until the drain completes and the stage drops)
     qtot = jnp.sum(q, axis=2)
     serve_tot = jnp.minimum(qtot, serve_rate) * act
-    frac = serve_tot / jnp.maximum(qtot, 1e-9)
+    # serve_tot <= qtot, so frac <= 1 under IEEE division; a TPU divides
+    # through an approximate reciprocal (x / x can exceed 1 by an ulp),
+    # which would serve more than the queue holds and leave a negative
+    # backlog that the 1e-9 floor then amplifies without bound. The
+    # clamp is a no-op where division is exact (bit-identical on CPU).
+    frac = jnp.minimum(serve_tot / jnp.maximum(qtot, 1e-9), 1.0)
     served = q * frac[..., None]
     q = q - served
 

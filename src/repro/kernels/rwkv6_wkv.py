@@ -27,7 +27,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import tpu_compiler_params
 
 CHUNK = 16
 
@@ -104,7 +103,7 @@ def wkv_chunked(r, k, v, w, u, state, *, chunk=CHUNK, interpret=True):
             jax.ShapeDtypeStruct((B, H, dh, dh), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((dh, dh), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(r, k, v, w, u, state.astype(jnp.float32))

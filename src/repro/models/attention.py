@@ -17,8 +17,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.compat import shard_map
-
 from repro.models.layers import apply_rope, init_dense, rms_norm
 
 NEG_INF = -1e30
@@ -343,7 +341,7 @@ def gqa_decode_sp(p, cfg, x, cache, pos, dist):
         out = acc / jnp.maximum(l_glob, 1e-30).transpose(0, 2, 1)[..., None]
         return out.astype(q.dtype), kc, vc
 
-    out, kc, vc = shard_map(
+    out, kc, vc = jax.shard_map(
         local_attend, mesh=dist.mesh,
         in_specs=(P(da, None, None, None), P(da, None, None, None),
                   P(da, None, None, None), P(da, ma, None, None),

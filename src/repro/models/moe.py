@@ -26,8 +26,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
-
 from repro.models.layers import init_dense
 
 
@@ -204,7 +202,7 @@ def moe_apply_dist(p, cfg, x, dist: DistContext):
     else:
         y_spec = x_spec
     out_specs = (y_spec, P())
-    y, aux = shard_map(
+    y, aux = jax.shard_map(
         local_moe, mesh=dist.mesh, in_specs=in_specs, out_specs=out_specs,
         check_vma=False)(x, p["router"], p["w_gate"], p["w_up"], p["w_down"])
     return y, aux

@@ -152,6 +152,48 @@ def test_prune_bounds_retained_files(tmp_path, reference):
     assert diff == 0.0, key
 
 
+def test_checkpoint_resume_with_donated_carry(tmp_path, reference,
+                                             monkeypatch):
+    """On an accelerator every chunk dispatch DONATES the carry it
+    consumes, deleting those buffers. Donation is forced here on the
+    CPU: cadenced snapshots, a kill + resume, the host-fold path and
+    return_state must never read a donated buffer, and the metrics
+    stay bit-identical (host fold: within its 1e-6 contract)."""
+    import jax
+    donating = jax.jit(S._sweep_chunk_impl,
+                       static_argnames=("site", "length", "validate"),
+                       donate_argnames=("state", "fold"))
+    monkeypatch.setattr(S, "_runner_for", lambda scen: donating)
+
+    h0 = S.HOST_TRANSFER_COUNT
+    res, state = S.run_sweep(_batch(), TICKS, chunk_ticks=CHUNK,
+                             validate=True, return_state=True,
+                             checkpoint=_spec(tmp_path, every_chunks=1))
+    assert S.HOST_TRANSFER_COUNT - h0 == 1 + 5
+    assert state.rsw_q.shape[0] == 3
+    diff, key = S.worst_parity(reference, res)
+    assert diff == 0.0, key
+
+    def hook(ci):
+        if ci == 3:
+            raise RuntimeError("preempted")
+
+    monkeypatch.setattr(S, "CHUNK_HOOK", hook)
+    with pytest.raises(RuntimeError, match="preempted"):
+        S.run_sweep(_batch(), TICKS, chunk_ticks=CHUNK, validate=True,
+                    checkpoint=_spec(tmp_path, tag="kill",
+                                     every_chunks=1))
+    monkeypatch.setattr(S, "CHUNK_HOOK", None)
+    found = CK.list_checkpoints(tmp_path, "kill")
+    assert [c for c, _ in found] == [1, 2]
+    diff, key = S.worst_parity(reference, S.resume_sweep(found[-1][1]))
+    assert diff == 0.0, key
+
+    host = S.run_sweep(_batch(), TICKS, chunk_ticks=CHUNK, validate=True,
+                       fold="host")
+    assert S.worst_parity(reference, host)[0] <= 1e-6
+
+
 def test_host_fold_checkpoint_rejected(tmp_path):
     """The host-fold path synchronizes per chunk already; checkpointing
     it would pin a second fetch discipline, so it is an upfront error

@@ -81,6 +81,20 @@ def test_wkv_chunked_vs_sequential(case):
                                rtol=tol)
 
 
+def _assert_switch_matches_ref(a, b):
+    """Kernel outputs vs the oracle's, output by output. Every output
+    is held to atol=1e-6 except occ_m2: a float32 sum of squared port
+    backlogs in the hundreds, whose summation order differs between the
+    kernel's lane reduction and the oracle's XLA reduce. Its rounding is
+    a few ulp of the total (L terms of up to 0.5 ulp each, ~1.2e-7
+    relative seen), so it gets rtol=1e-6 on top of the shared atol."""
+    assert len(a) == len(b) == 8
+    for i, (x, y) in enumerate(zip(a, b)):
+        np.testing.assert_allclose(np.asarray(x, np.float32),
+                                   np.asarray(y, np.float32), atol=1e-6,
+                                   rtol=1e-6 if i == 7 else 1e-7)
+
+
 # odd S (16, 100) exercises the switch-axis padding: tiers need not be
 # a multiple of the block (e.g. the 16-CSW tier under a 128 block)
 @pytest.mark.parametrize("S,L,block", [(128, 4, 64), (256, 4, 128),
@@ -92,12 +106,9 @@ def test_switch_step_vs_ref(S, L, block):
     q = jax.random.uniform(ks[0], (S, L)) * 20
     stage = jax.random.randint(ks[1], (S,), 1, L + 1)
     arr = jax.random.uniform(ks[2], (S,)) * 3
-    a = switch_step(q, stage, arr, block_s=block)
+    a = switch_step(q, stage, arr, block_s=block, interpret=True)
     b = ref.switch_step_ref(q, stage, arr)
-    assert len(a) == len(b) == 8
-    for x, y in zip(a, b):
-        np.testing.assert_allclose(np.asarray(x, np.float32),
-                                   np.asarray(y, np.float32), atol=1e-6)
+    _assert_switch_matches_ref(a, b)
 
 
 # the extended datapath the simulator hot loop uses: K-component queues
@@ -113,11 +124,10 @@ def test_switch_step_components_vs_ref(S, L, K, serve_rate, block):
     arr = jax.random.uniform(ks[2], (S, K)) * 2
     drain = jax.random.bernoulli(ks[3], 0.4, (S,))
     kw = dict(cap=17.0, hi=0.6, lo=0.3, serve_rate=serve_rate)
-    a = switch_step(q, stage, arr, drain, block_s=block, **kw)
+    a = switch_step(q, stage, arr, drain, block_s=block, interpret=True,
+                    **kw)
     b = ref.switch_step_ref(q, stage, arr, drain, **kw)
-    for x, y in zip(a, b):
-        np.testing.assert_allclose(np.asarray(x, np.float32),
-                                   np.asarray(y, np.float32), atol=1e-6)
+    _assert_switch_matches_ref(a, b)
 
 
 @pytest.mark.parametrize("S,L,K,block", [(64, 4, 2, 32), (100, 3, 1, 128)])
@@ -131,11 +141,10 @@ def test_switch_step_valid_mask_vs_ref(S, L, K, block):
     valid = jax.random.bernoulli(ks[2], 0.6, (S,))
     # contract: invalid switches receive zero arrivals
     arr = jax.random.uniform(ks[3], (S, K)) * 2 * valid[:, None]
-    a = switch_step(q, stage, arr, valid=valid, block_s=block)
+    a = switch_step(q, stage, arr, valid=valid, block_s=block,
+                    interpret=True)
     b = ref.switch_step_ref(q, stage, arr, valid=valid)
-    for x, y in zip(a, b):
-        np.testing.assert_allclose(np.asarray(x, np.float32),
-                                   np.asarray(y, np.float32), atol=1e-6)
+    _assert_switch_matches_ref(a, b)
     nq, served, hi_t, lo_t, drop, wait, occ_m1, occ_m2 = b
     inv = ~np.asarray(valid)
     np.testing.assert_allclose(np.asarray(nq)[inv], np.asarray(q)[inv])
@@ -163,11 +172,10 @@ def test_switch_step_per_link_valid_vs_ref(S, L, K, block):
     # accounting is actually exercised
     link_valid = link_valid.at[:4].set(False)
     arr = jax.random.uniform(ks[3], (S, K)) * 2
-    a = switch_step(q, stage, arr, valid=link_valid, block_s=block)
+    a = switch_step(q, stage, arr, valid=link_valid, block_s=block,
+                    interpret=True)
     b = ref.switch_step_ref(q, stage, arr, valid=link_valid)
-    for x, y in zip(a, b):
-        np.testing.assert_allclose(np.asarray(x, np.float32),
-                                   np.asarray(y, np.float32), atol=1e-6)
+    _assert_switch_matches_ref(a, b)
     nq, served, _, _, drop, _, _, _ = b
     dead = ~np.asarray(link_valid)
     # dead ports: untouched backlog, zero service
@@ -190,11 +198,9 @@ def test_switch_step_per_switch_cap_vs_ref():
     stage = jax.random.randint(ks[1], (S,), 1, L + 1)
     arr = jax.random.uniform(ks[2], (S,)) * 3
     cap = jnp.linspace(10.0, 25.0, S)
-    a = switch_step(q, stage, arr, cap=cap, block_s=128)
+    a = switch_step(q, stage, arr, cap=cap, block_s=128, interpret=True)
     b = ref.switch_step_ref(q, stage, arr, cap=cap)
-    for x, y in zip(a, b):
-        np.testing.assert_allclose(np.asarray(x, np.float32),
-                                   np.asarray(y, np.float32), atol=1e-6)
+    _assert_switch_matches_ref(a, b)
 
 
 def test_switch_step_drain_blocks_enqueue_but_serves():
