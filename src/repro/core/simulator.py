@@ -152,10 +152,11 @@ where the backend enables x64, otherwise a compensated Kahan float32
 accumulators are re-zeroed, all without leaving the device. The chunk
 loop is therefore pure async dispatch — no host synchronization at
 chunk boundaries — and the entire run performs exactly ONE host
-transfer (the final fold fetch; ``HOST_TRANSFER_COUNT`` counts these so
-benchmarks/bench_sweep.py can gate it). Kahan compensation bounds the
-cross-chunk float32 accumulation error at O(eps) independent of chunk
-count, so device-fold metrics match the legacy host-fold path
+transfer (the final fold fetch; ``HOST_TRANSFER_COUNT`` counts these:
+the tests pin it, and chipbench/metrics/host_transfers_per_sweep.py
+reads it per sweep). Kahan compensation bounds the cross-chunk float32
+accumulation error at O(eps) independent of chunk count, so
+device-fold metrics match the legacy host-fold path
 (``fold="host"``: per-chunk ``device_get`` + float64 numpy fold, kept
 for parity pinning) to <= 1e-6 relative.
 
@@ -204,6 +205,7 @@ from typing import NamedTuple, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core import checkpoint as _ckpt
 from repro.core import constants as C
@@ -263,7 +265,8 @@ TRACE_HOOK = None
 #: (``device_get`` of fold buffers / in-scan accumulators). The
 #: device-resident fold path does exactly ONE per run_sweep (one per
 #: planned bucket); the legacy ``fold="host"`` path does one per chunk.
-#: benchmarks/bench_sweep.py gates transfers-per-bucket <= 1 on this.
+#: tests/test_sweep.py and tests/test_durability.py pin it, and
+#: chipbench/metrics/host_transfers_per_sweep.py reads it per sweep.
 HOST_TRANSFER_COUNT = 0
 
 #: scalar metrics that must agree between run_sim and run_sweep — the
@@ -831,10 +834,11 @@ def _spawn_flows(scen: Scenario, k_u, k_z, rack_uid, rack_valid,
     Returns the updated flow state plus this tick's per-flow pace
     uniforms (R, F_SLOTS).
     """
-    ku = jax.vmap(lambda i: jax.random.fold_in(k_u, i))(rack_uid)
-    kz = jax.vmap(lambda i: jax.random.fold_in(k_z, i))(rack_uid)
-    u = jax.vmap(lambda k: jax.random.uniform(k, (5 + F_SLOTS,)))(ku)
-    z = jax.vmap(lambda k: jax.random.normal(k, (2,)))(kz)
+    with jax.named_scope("draws"):
+        ku = jax.vmap(lambda i: jax.random.fold_in(k_u, i))(rack_uid)
+        kz = jax.vmap(lambda i: jax.random.fold_in(k_z, i))(rack_uid)
+        u = jax.vmap(lambda k: jax.random.uniform(k, (5 + F_SLOTS,)))(ku)
+        z = jax.vmap(lambda k: jax.random.normal(k, (2,)))(kz)
 
     # ON/OFF burst Markov
     stay_on = u[:, 0] > scen.p_on_off
@@ -848,17 +852,19 @@ def _spawn_flows(scen: Scenario, k_u, k_z, rack_uid, rack_valid,
     spawn = (u[:, 2] < scen.p_spawn) & burst_on & rack_valid \
         & (scen.flow_mode == 0)
 
-    # lognormal mixture sizes -> packets (1250 B per packet)
-    pick_mix = u[:, 3] < scen.size_w
-    size_b = jnp.where(pick_mix,
-                       jnp.exp(scen.size_mu1 + scen.size_s1 * z[:, 0]),
-                       jnp.exp(scen.size_mu2 + scen.size_s2 * z[:, 1]))
-    size_p = jnp.maximum(jnp.ceil(size_b / 1250.0), 1.0).astype(jnp.int32)
-
-    ud = u[:, 4]
-    dest = jnp.where(ud < scen.p_intra_rack, 0,
-                     jnp.where(ud < scen.p_intra_rack + scen.p_intra_cluster,
-                               1, 2)).astype(jnp.int32)
+    with jax.named_scope("draws"):
+        # lognormal mixture sizes -> packets (1250 B per packet)
+        pick_mix = u[:, 3] < scen.size_w
+        size_b = jnp.where(pick_mix,
+                           jnp.exp(scen.size_mu1 + scen.size_s1 * z[:, 0]),
+                           jnp.exp(scen.size_mu2 + scen.size_s2 * z[:, 1]))
+        size_p = jnp.maximum(jnp.ceil(size_b / 1250.0),
+                             1.0).astype(jnp.int32)
+        ud = u[:, 4]
+        dest = jnp.where(
+            ud < scen.p_intra_rack, 0,
+            jnp.where(ud < scen.p_intra_rack + scen.p_intra_cluster,
+                      1, 2)).astype(jnp.int32)
 
     free = flow_rem == 0
     first_free = jnp.argmax(free, axis=1)               # (R,)
@@ -880,7 +886,14 @@ def make_sim_step(hull: FBSite):
     """One tick for ONE scenario on the static padded ``hull``; every
     scenario knob — including the scenario's real site dims — is a
     traced scalar, so jax.vmap(step) batches arbitrarily many scenarios
-    (on heterogeneous sites fitting the hull) per compile."""
+    (on heterogeneous sites fitting the hull) per compile.
+
+    Each part of the step runs under a ``jax.named_scope``: ``edge``,
+    ``flows``, ``rsw``, ``csw``, ``down``, ``stats`` and ``gating``,
+    with a nested ``draws`` around the random draws and the transforms
+    of uniforms into samples. The scopes change only the ops' metadata
+    (``op_name``); chipbench/progtrace.py reads them from a profile to
+    split the step's device time by part."""
     s = hull
     NCL, RPC = s.n_clusters, s.racks_per_cluster
     P = s.csw_per_cluster     # plane axis: RSW uplink c IS cluster-CSW c
@@ -897,557 +910,575 @@ def make_sim_step(hull: FBSite):
             _site_masks(hull, scen)
         rpcf = scen.rpc.astype(jnp.float32)
         nclf = scen.ncl.astype(jnp.float32)
-        key, k_u, k_z = jax.random.split(state.key, 3)
+        with jax.named_scope("draws"):
+            key, k_u, k_z = jax.random.split(state.key, 3)
 
-        # fault-model randomness: dedicated fold_in branches of the tick
-        # key (constants far above any logical switch id) so the
-        # existing traffic streams are bit-untouched, then one
-        # FIXED-width uniform block per switch keyed by its LOGICAL id —
-        # identical draws whether a site runs at exact dims or padded
-        # inside a heterogeneous hull. Layout: [0]=wake jitter,
-        # [1]=wake-failure, [2+l]=hard-fault hazard of link l.
-        k_fr = jax.random.fold_in(k_u, 0x7F000001)
-        k_fc = jax.random.fold_in(k_u, 0x7F000002)
-        csw_uid = ((jnp.arange(NC) // P) * scen.cpc
-                   + jnp.arange(NC) % P).astype(jnp.int32)
+        with jax.named_scope("gating"):
+            # fault-model randomness: dedicated fold_in branches of the tick
+            # key (constants far above any logical switch id) so the
+            # existing traffic streams are bit-untouched, then one
+            # FIXED-width uniform block per switch keyed by its LOGICAL id —
+            # identical draws whether a site runs at exact dims or padded
+            # inside a heterogeneous hull. Layout: [0]=wake jitter,
+            # [1]=wake-failure, [2+l]=hard-fault hazard of link l.
+            with jax.named_scope("draws"):
+                k_fr = jax.random.fold_in(k_u, 0x7F000001)
+                k_fc = jax.random.fold_in(k_u, 0x7F000002)
+            csw_uid = ((jnp.arange(NC) // P) * scen.cpc
+                       + jnp.arange(NC) % P).astype(jnp.int32)
 
-        def fault_draws(base, uids):
-            ks = jax.vmap(lambda i: jax.random.fold_in(base, i))(uids)
-            return jax.vmap(
-                lambda k: jax.random.uniform(k, (2 + MAX_FAULT_LINKS,))
-            )(ks)
+            def fault_draws(base, uids):
+                ks = jax.vmap(lambda i: jax.random.fold_in(base, i))(uids)
+                return jax.vmap(
+                    lambda k: jax.random.uniform(k, (2 + MAX_FAULT_LINKS,))
+                )(ks)
 
-        u_fr = fault_draws(k_fr, rack_uid)                  # (R, 2+16)
-        u_fc = fault_draws(k_fc, csw_uid)                   # (NC, 2+16)
+            with jax.named_scope("draws"):
+                u_fr = fault_draws(k_fr, rack_uid)              # (R, 2+16)
+                u_fc = fault_draws(k_fc, csw_uid)               # (NC, 2+16)
 
-        # correlated failure domains (plane_fail_prob): ONE hazard draw
-        # per shared laser comb, broadcast to every link it feeds, so a
-        # comb death takes the whole plane down in one tick. RSW tier:
-        # plane p of cluster k is fed by cluster-CSW (k, p) — all of
-        # cluster k's rack uplinks p share one draw. CSW tier: FC f's
-        # comb feeds csw uplink f site-wide — one draw per FC. New
-        # dedicated fold_in branches + the fixed MAX_FAULT_LINKS draw
-        # width keep every existing stream bit-untouched and the draws
-        # padding-invariant (cluster/plane ids are logical hull
-        # positions; real dims are prefix slices of the fixed block).
-        k_pr = jax.random.fold_in(k_u, 0x7F000005)
-        k_pc = jax.random.fold_in(k_u, 0x7F000006)
-        u_plane_cl = jax.vmap(
-            lambda k: jax.random.uniform(k, (MAX_FAULT_LINKS,)))(
-            jax.vmap(lambda i: jax.random.fold_in(k_pr, i))(
-                jnp.arange(NCL, dtype=jnp.int32)))          # (NCL, 16)
-        u_plane_r = jnp.broadcast_to(
-            u_plane_cl[:, None, :P], (NCL, RPC, P)).reshape(R, P)
-        u_plane_c = jnp.broadcast_to(
-            jax.random.uniform(k_pc, (MAX_FAULT_LINKS,))[None, :CUP],
-            (NC, CUP))
+            # correlated failure domains (plane_fail_prob): ONE hazard draw
+            # per shared laser comb, broadcast to every link it feeds, so a
+            # comb death takes the whole plane down in one tick. RSW tier:
+            # plane p of cluster k is fed by cluster-CSW (k, p) — all of
+            # cluster k's rack uplinks p share one draw. CSW tier: FC f's
+            # comb feeds csw uplink f site-wide — one draw per FC. New
+            # dedicated fold_in branches + the fixed MAX_FAULT_LINKS draw
+            # width keep every existing stream bit-untouched and the draws
+            # padding-invariant (cluster/plane ids are logical hull
+            # positions; real dims are prefix slices of the fixed block).
+            with jax.named_scope("draws"):
+                k_pr = jax.random.fold_in(k_u, 0x7F000005)
+                k_pc = jax.random.fold_in(k_u, 0x7F000006)
+                u_plane_cl = jax.vmap(
+                    lambda k: jax.random.uniform(k, (MAX_FAULT_LINKS,)))(
+                    jax.vmap(lambda i: jax.random.fold_in(k_pr, i))(
+                        jnp.arange(NCL, dtype=jnp.int32)))          # (NCL, 16)
+                u_plane_r = jnp.broadcast_to(
+                    u_plane_cl[:, None, :P], (NCL, RPC, P)).reshape(R, P)
+                u_plane_c = jnp.broadcast_to(
+                    jax.random.uniform(k_pc, (MAX_FAULT_LINKS,))[None, :CUP],
+                    (NC, CUP))
 
-        rsw_ok = state.rsw_fault.timer == 0                 # (R, P)
-        csw_ok = state.csw_fault.timer == 0                 # (NC, CUP)
-        link_idx_p = jnp.arange(P)[None, :]
-        link_idx_c = jnp.arange(CUP)[None, :]
-        rsw_link_real = rack_valid[:, None] & (link_idx_p
-                                               < rsw_max[:, None])
-        csw_link_real = csw_valid[:, None] & (link_idx_c
-                                              < csw_max[:, None])
+            rsw_ok = state.rsw_fault.timer == 0                 # (R, P)
+            csw_ok = state.csw_fault.timer == 0                 # (NC, CUP)
+            link_idx_p = jnp.arange(P)[None, :]
+            link_idx_c = jnp.arange(CUP)[None, :]
+            rsw_link_real = rack_valid[:, None] & (link_idx_p
+                                                   < rsw_max[:, None])
+            csw_link_real = csw_valid[:, None] & (link_idx_c
+                                                  < csw_max[:, None])
 
-        # 1. traffic edge ------------------------------------------------
-        burst_on, flow_rem, flow_dest, flow_fast, pace_u = _spawn_flows(
-            scen, k_u, k_z, rack_uid, rack_valid, state.burst_on,
-            state.flow_rem, state.flow_dest, state.flow_fast)
-        active = flow_rem > 0                                   # (R,F)
-        # paced emission: mice trickle below line rate (boosted during
-        # bursts); elephants transmit at line rate -- overlapping
-        # elephants are what push queues over the high watermark.
-        pace_eff = jnp.minimum(
-            scen.pace * jnp.where(burst_on, scen.burst_pace_boost, 1.0),
-            1.0)[:, None]
-        pace_flow = jnp.where(flow_fast, scen.elephant_pace, pace_eff)
-        emit = active & (pace_u < pace_flow)
-        n_holding = jnp.sum(active, axis=1).astype(jnp.float32)  # (R,)
-        by_dest = jnp.stack(
-            [jnp.sum(emit & (flow_dest == d), axis=1) for d in (0, 1, 2)],
-            axis=1).astype(jnp.float32)                          # (R,3)
-        flow_rem = jnp.maximum(flow_rem - emit.astype(jnp.int32), 0)
+        with jax.named_scope("edge"):
+            # 1. traffic edge ------------------------------------------------
+            burst_on, flow_rem, flow_dest, flow_fast, pace_u = _spawn_flows(
+                scen, k_u, k_z, rack_uid, rack_valid, state.burst_on,
+                state.flow_rem, state.flow_dest, state.flow_fast)
+            active = flow_rem > 0                                   # (R,F)
+            # paced emission: mice trickle below line rate (boosted during
+            # bursts); elephants transmit at line rate -- overlapping
+            # elephants are what push queues over the high watermark.
+            pace_eff = jnp.minimum(
+                scen.pace * jnp.where(burst_on, scen.burst_pace_boost, 1.0),
+                1.0)[:, None]
+            pace_flow = jnp.where(flow_fast, scen.elephant_pace, pace_eff)
+            emit = active & (pace_u < pace_flow)
+            n_holding = jnp.sum(active, axis=1).astype(jnp.float32)  # (R,)
+            by_dest = jnp.stack(
+                [jnp.sum(emit & (flow_dest == d), axis=1) for d in (0, 1, 2)],
+                axis=1).astype(jnp.float32)                          # (R,3)
+            flow_rem = jnp.maximum(flow_rem - emit.astype(jnp.int32), 0)
 
-        # 1b. flow-level workload engine (flow_mode=1): fixed-capacity
-        # per-rack flow table, pFabric-style heavy-tailed sizes
-        # (core/workloads.py), AIMD cwnd — all array ops selected
-        # against the rate-based path above by jnp.where, so both modes
-        # share ONE compiled program and flow_mode=0 stays bit-identical
-        # to the pre-flow engine (the fault-knob discipline: dedicated
-        # fold_in branches, fixed draw widths, masked accumulator adds).
-        flow_on = scen.flow_mode > 0
-        tick_now = state.tick + 1
-        FT = C.FLOW_TABLE_SLOTS
-        k_fa = jax.random.fold_in(k_u, 0x7F000003)   # arrival + dst
-        k_fs = jax.random.fold_in(k_u, 0x7F000004)   # flow sizes
-        ka = jax.vmap(lambda i: jax.random.fold_in(k_fa, i))(rack_uid)
-        ua = jax.vmap(lambda k: jax.random.uniform(k, (2,)))(ka)
-        ks = jax.vmap(lambda i: jax.random.fold_in(k_fs, i))(rack_uid)
-        us = jax.vmap(lambda k: jax.random.uniform(
-            k, (C.MAX_INCAST_DEGREE,)))(ks)          # fixed draw width
-        # one arrival EVENT spawns `incast` flows converging on the
-        # same destination class (the fan-in pattern that stresses the
-        # table and the watermark controller together)
-        arrive = (ua[:, 0] < scen.flow_rate) & rack_valid & flow_on
-        n_new = jnp.where(arrive, scen.incast, 0)            # (R,)
-        sizes = workloads.sample_flow_size_pkts(
-            us, scen.flow_dist)                              # (R,W) f32
-        ud2 = ua[:, 1]
-        fdst = jnp.where(
-            ud2 < scen.p_intra_rack, 0,
-            jnp.where(ud2 < scen.p_intra_rack + scen.p_intra_cluster,
-                      1, 2)).astype(jnp.int32)               # (R,)
-        # admission: rank the usable free slots (traced flow_cap caps
-        # the static FT axis) and match candidate k to the k-th free
-        # slot — a whole incast burst admits in one tick, overflow is
-        # EVICTION (counted; started == completed + evicted + in-flight
-        # stays exact)
-        slot_i = jnp.arange(FT)[None, :]
-        usable_slot = slot_i < scen.flow_cap                 # (1,FT)
-        pre_live = (state.ft_rem > 0.0) & usable_slot        # (R,FT)
-        free = ~pre_live & usable_slot
-        rank = jnp.cumsum(free.astype(jnp.int32), axis=1) - 1
-        cand = jnp.arange(C.MAX_INCAST_DEGREE)
-        want = cand[None, :] < n_new[:, None]                # (R,W)
-        place = (free[:, :, None]
-                 & (rank[:, :, None] == cand[None, None, :])
-                 & want[:, None, :])                         # (R,FT,W)
-        admitted = jnp.any(place, axis=1)                    # (R,W)
-        placed = jnp.any(place, axis=2)                      # (R,FT)
-        new_sz = jnp.sum(jnp.where(place, sizes[:, None, :], 0.0),
-                         axis=2)                             # (R,FT)
-        # AIMD on the PREVIOUS tick's live flows: halve on the rack's
-        # hi-watermark congestion signal (previous tick's RSW queues —
-        # the 1-tick feedback delay of a real rack-local signal),
-        # additive increase toward line rate otherwise
-        cong, _ = gating.watermark_triggers(
-            jnp.sum(state.rsw_q, axis=2), state.rsw_gate.stage,
-            cap=scen.queue_cap, hi=scen.hi, lo=scen.lo)
-        ft_cwnd = jnp.where(
-            pre_live,
-            jnp.where(cong[:, None],
-                      jnp.maximum(state.ft_cwnd * C.FLOW_AIMD_DECREASE,
-                                  C.FLOW_CWND_MIN_PPT),
-                      jnp.minimum(state.ft_cwnd
-                                  + C.FLOW_AIMD_INCREASE_PPT,
-                                  C.FLOW_LINE_RATE_PPT)),
-            state.ft_cwnd)
-        ft_start = jnp.where(placed, tick_now, state.ft_start)
-        ft_rem = jnp.where(placed, new_sz, state.ft_rem)
-        ft_size = jnp.where(placed, new_sz.astype(jnp.int32),
-                            state.ft_size)
-        ft_dst = jnp.where(placed, fdst[:, None], state.ft_dst)
-        ft_cwnd = jnp.where(placed, C.FLOW_CWND_INIT_PPT, ft_cwnd)
-        # emission: every live flow sends min(rem, cwnd) this tick
-        # (fluid, like the aggregation tiers); the last fraction
-        # completes the flow
-        ft_live = (ft_rem > 0.0) & usable_slot
-        emit_f = jnp.where(ft_live, jnp.minimum(ft_rem, ft_cwnd), 0.0)
-        ft_rem = ft_rem - emit_f
-        done = ft_live & (ft_rem <= 0.0)                     # (R,FT)
-        flow_by_dest = jnp.stack(
-            [jnp.sum(jnp.where(ft_dst == d, emit_f, 0.0), axis=1)
-             for d in (0, 1, 2)], axis=1)                    # (R,3)
-        # select the traffic edge the datapath sees; at flow_mode=0
-        # every flow accumulator add below is exactly +0.0
-        by_dest = jnp.where(flow_on, flow_by_dest, by_dest)
-        n_holding = jnp.where(
-            flow_on, jnp.sum(ft_live, axis=1).astype(jnp.float32),
-            n_holding)
-        acc["flows_started"] += jnp.sum(n_new).astype(jnp.float32)
-        acc["flows_evicted"] += (jnp.sum(n_new)
-                                 - jnp.sum(admitted)).astype(jnp.float32)
+        with jax.named_scope("flows"):
+            # 1b. flow-level workload engine (flow_mode=1): fixed-capacity
+            # per-rack flow table, pFabric-style heavy-tailed sizes
+            # (core/workloads.py), AIMD cwnd — all array ops selected
+            # against the rate-based path above by jnp.where, so both modes
+            # share ONE compiled program and flow_mode=0 stays bit-identical
+            # to the pre-flow engine (the fault-knob discipline: dedicated
+            # fold_in branches, fixed draw widths, masked accumulator adds).
+            flow_on = scen.flow_mode > 0
+            tick_now = state.tick + 1
+            FT = C.FLOW_TABLE_SLOTS
+            with jax.named_scope("draws"):
+                k_fa = jax.random.fold_in(k_u, 0x7F000003)   # arrival + dst
+                k_fs = jax.random.fold_in(k_u, 0x7F000004)   # flow sizes
+                ka = jax.vmap(lambda i: jax.random.fold_in(k_fa, i))(rack_uid)
+                ua = jax.vmap(lambda k: jax.random.uniform(k, (2,)))(ka)
+                ks = jax.vmap(lambda i: jax.random.fold_in(k_fs, i))(rack_uid)
+                us = jax.vmap(lambda k: jax.random.uniform(
+                    k, (C.MAX_INCAST_DEGREE,)))(ks)          # fixed draw width
+            # one arrival EVENT spawns `incast` flows converging on the
+            # same destination class (the fan-in pattern that stresses the
+            # table and the watermark controller together)
+            arrive = (ua[:, 0] < scen.flow_rate) & rack_valid & flow_on
+            n_new = jnp.where(arrive, scen.incast, 0)            # (R,)
+            with jax.named_scope("draws"):
+                sizes = workloads.sample_flow_size_pkts(
+                    us, scen.flow_dist)                          # (R,W) f32
+                ud2 = ua[:, 1]
+                fdst = jnp.where(
+                    ud2 < scen.p_intra_rack, 0,
+                    jnp.where(ud2 < scen.p_intra_rack + scen.p_intra_cluster,
+                              1, 2)).astype(jnp.int32)               # (R,)
+            # admission: rank the usable free slots (traced flow_cap caps
+            # the static FT axis) and match candidate k to the k-th free
+            # slot — a whole incast burst admits in one tick, overflow is
+            # EVICTION (counted; started == completed + evicted + in-flight
+            # stays exact)
+            slot_i = jnp.arange(FT)[None, :]
+            usable_slot = slot_i < scen.flow_cap                 # (1,FT)
+            pre_live = (state.ft_rem > 0.0) & usable_slot        # (R,FT)
+            free = ~pre_live & usable_slot
+            rank = jnp.cumsum(free.astype(jnp.int32), axis=1) - 1
+            cand = jnp.arange(C.MAX_INCAST_DEGREE)
+            want = cand[None, :] < n_new[:, None]                # (R,W)
+            place = (free[:, :, None]
+                     & (rank[:, :, None] == cand[None, None, :])
+                     & want[:, None, :])                         # (R,FT,W)
+            admitted = jnp.any(place, axis=1)                    # (R,W)
+            placed = jnp.any(place, axis=2)                      # (R,FT)
+            new_sz = jnp.sum(jnp.where(place, sizes[:, None, :], 0.0),
+                             axis=2)                             # (R,FT)
+            # AIMD on the PREVIOUS tick's live flows: halve on the rack's
+            # hi-watermark congestion signal (previous tick's RSW queues —
+            # the 1-tick feedback delay of a real rack-local signal),
+            # additive increase toward line rate otherwise
+            cong, _ = gating.watermark_triggers(
+                jnp.sum(state.rsw_q, axis=2), state.rsw_gate.stage,
+                cap=scen.queue_cap, hi=scen.hi, lo=scen.lo)
+            ft_cwnd = jnp.where(
+                pre_live,
+                jnp.where(cong[:, None],
+                          jnp.maximum(state.ft_cwnd * C.FLOW_AIMD_DECREASE,
+                                      C.FLOW_CWND_MIN_PPT),
+                          jnp.minimum(state.ft_cwnd
+                                      + C.FLOW_AIMD_INCREASE_PPT,
+                                      C.FLOW_LINE_RATE_PPT)),
+                state.ft_cwnd)
+            ft_start = jnp.where(placed, tick_now, state.ft_start)
+            ft_rem = jnp.where(placed, new_sz, state.ft_rem)
+            ft_size = jnp.where(placed, new_sz.astype(jnp.int32),
+                                state.ft_size)
+            ft_dst = jnp.where(placed, fdst[:, None], state.ft_dst)
+            ft_cwnd = jnp.where(placed, C.FLOW_CWND_INIT_PPT, ft_cwnd)
+            # emission: every live flow sends min(rem, cwnd) this tick
+            # (fluid, like the aggregation tiers); the last fraction
+            # completes the flow
+            ft_live = (ft_rem > 0.0) & usable_slot
+            emit_f = jnp.where(ft_live, jnp.minimum(ft_rem, ft_cwnd), 0.0)
+            ft_rem = ft_rem - emit_f
+            done = ft_live & (ft_rem <= 0.0)                     # (R,FT)
+            flow_by_dest = jnp.stack(
+                [jnp.sum(jnp.where(ft_dst == d, emit_f, 0.0), axis=1)
+                 for d in (0, 1, 2)], axis=1)                    # (R,3)
+            # select the traffic edge the datapath sees; at flow_mode=0
+            # every flow accumulator add below is exactly +0.0
+            by_dest = jnp.where(flow_on, flow_by_dest, by_dest)
+            n_holding = jnp.where(
+                flow_on, jnp.sum(ft_live, axis=1).astype(jnp.float32),
+                n_holding)
+            acc["flows_started"] += jnp.sum(n_new).astype(jnp.float32)
+            acc["flows_evicted"] += (jnp.sum(n_new)
+                                     - jnp.sum(admitted)).astype(jnp.float32)
 
-        acc["injected"] += jnp.sum(by_dest[:, 1:])
-        acc["intra_rack"] += jnp.sum(by_dest[:, 0])
+            acc["injected"] += jnp.sum(by_dest[:, 1:])
+            acc["intra_rack"] += jnp.sum(by_dest[:, 0])
 
-        # 2+3. RSW datapath tick: min-backlog enqueue of the [intra,
-        # inter] arrival split + 1 pkt/tick serve per active uplink —
-        # the shared switch-step kernel (Pallas on TPU, ref on CPU).
-        # The valid mask is per-LINK: hull padding AND hard-faulted
-        # transceivers (a dead port neither accepts nor serves).
-        (rsw_q, served_split, _, _, rsw_drop, rsw_wait, rsw_m1,
-         rsw_m2) = ops.switch_step(
-            state.rsw_q, state.rsw_gate.stage, by_dest[:, 1:],
-            state.rsw_gate.draining, valid=rack_valid[:, None] & rsw_ok,
-            cap=scen.queue_cap, hi=scen.hi, lo=scen.lo, serve_rate=1.0)
-        acc["drops"] += jnp.sum(rsw_drop)
-        acc["rsw_backlog"] += jnp.sum(rsw_q) + jnp.sum(served_split)
-        acc["rsw_served"] += jnp.sum(served_split)
-        acc["rsw_occ_m1"] += jnp.sum(rsw_m1)
-        acc["rsw_occ_m2"] += jnp.sum(rsw_m2)
+        with jax.named_scope("rsw"):
+            # 2+3. RSW datapath tick: min-backlog enqueue of the [intra,
+            # inter] arrival split + 1 pkt/tick serve per active uplink —
+            # the shared switch-step kernel (Pallas on TPU, ref on CPU).
+            # The valid mask is per-LINK: hull padding AND hard-faulted
+            # transceivers (a dead port neither accepts nor serves).
+            (rsw_q, served_split, _, _, rsw_drop, rsw_wait, rsw_m1,
+             rsw_m2) = ops.switch_step(
+                state.rsw_q, state.rsw_gate.stage, by_dest[:, 1:],
+                state.rsw_gate.draining, valid=rack_valid[:, None] & rsw_ok,
+                cap=scen.queue_cap, hi=scen.hi, lo=scen.lo, serve_rate=1.0)
+            acc["drops"] += jnp.sum(rsw_drop)
+            acc["rsw_backlog"] += jnp.sum(rsw_q) + jnp.sum(served_split)
+            acc["rsw_served"] += jnp.sum(served_split)
+            acc["rsw_occ_m1"] += jnp.sum(rsw_m1)
+            acc["rsw_occ_m2"] += jnp.sum(rsw_m2)
 
-        # uplink c of rack r lands on CSW (cluster(r), c): the uplink
-        # axis IS the csw_per_cluster plane axis (FBSite invariant)
-        srv_rc = served_split.reshape(NCL, RPC, P, 2)
-        to_csw = jnp.sum(srv_rc, axis=1)                         # (NCL,P,2)
-        inter_in = to_csw[..., 1].reshape(NC)
+        with jax.named_scope("csw"):
+            # uplink c of rack r lands on CSW (cluster(r), c): the uplink
+            # axis IS the csw_per_cluster plane axis (FBSite invariant)
+            srv_rc = served_split.reshape(NCL, RPC, P, 2)
+            to_csw = jnp.sum(srv_rc, axis=1)                     # (NCL,P,2)
+            inter_in = to_csw[..., 1].reshape(NC)
 
-        # Stage-aware down-plane weights (the per-stage CAM tables of
-        # Sec III-B): traffic for rack r rides plane c with weight
-        # active(r,c)/stage(r); dest racks are uniform within the
-        # cluster. Padded hull rows carry zero weight.
-        rsw_stage_f = state.rsw_gate.stage.astype(jnp.float32)
-        plane_w = (jnp.arange(P)[None, :] < state.rsw_gate.stage[:, None]) \
-            / rsw_stage_f[:, None] * rack_valid[:, None]         # (R,P)
-        plane_w_c = plane_w.reshape(NCL, RPC, P)
+            # Stage-aware down-plane weights (the per-stage CAM tables of
+            # Sec III-B): traffic for rack r rides plane c with weight
+            # active(r,c)/stage(r); dest racks are uniform within the
+            # cluster. Padded hull rows carry zero weight.
+            rsw_stage_f = state.rsw_gate.stage.astype(jnp.float32)
+            plane_w = (jnp.arange(P)[None, :]
+                       < state.rsw_gate.stage[:, None]) \
+                / rsw_stage_f[:, None] * rack_valid[:, None]         # (R,P)
+            plane_w_c = plane_w.reshape(NCL, RPC, P)
 
-        # 4. CSW: intra-cluster traffic -> down queues. A packet for rack
-        # r arriving UP at csw c may have to cross to plane c' active for
-        # r; within a cluster that crossing is the CSW ring. We charge the
-        # ring for the mismatch between arrival plane and dest plane.
-        intra_cl = jnp.sum(to_csw[..., 0], axis=1)               # (NCL,)
-        dest_share = intra_cl[:, None, None] / rpcf * \
-            plane_w_c.transpose(0, 2, 1)                         # (NCL,P,RPC)
-        csw_down_q = state.csw_down_q + dest_share.reshape(NC, RPC)
-        # ring charge: fraction of intra traffic whose up-plane != down-plane
-        up_share = to_csw[..., 0] / jnp.maximum(intra_cl[:, None], 1e-9)
-        # per-plane mean dest weight over the cluster's REAL racks
-        mean_down = jnp.sum(plane_w_c, axis=1) / rpcf            # (NCL,P)
-        same_plane = jnp.sum(jnp.minimum(up_share, mean_down), axis=1)
-        acc["ring_pkts"] += jnp.sum(intra_cl * (1.0 - same_plane))
+            # 4. CSW: intra-cluster traffic -> down queues. A packet for rack
+            # r arriving UP at csw c may have to cross to plane c' active for
+            # r; within a cluster that crossing is the CSW ring. We charge the
+            # ring for the mismatch between arrival plane and dest plane.
+            intra_cl = jnp.sum(to_csw[..., 0], axis=1)               # (NCL,)
+            dest_share = intra_cl[:, None, None] / rpcf * \
+                plane_w_c.transpose(0, 2, 1)                     # (NCL,P,RPC)
+            csw_down_q = state.csw_down_q + dest_share.reshape(NC, RPC)
+            # ring charge: fraction of intra traffic whose up-plane !=
+            # down-plane
+            up_share = to_csw[..., 0] / jnp.maximum(intra_cl[:, None], 1e-9)
+            # per-plane mean dest weight over the cluster's REAL racks
+            mean_down = jnp.sum(plane_w_c, axis=1) / rpcf            # (NCL,P)
+            same_plane = jnp.sum(jnp.minimum(up_share, mean_down), axis=1)
+            acc["ring_pkts"] += jnp.sum(intra_cl * (1.0 - same_plane))
 
-        # 5. CSW uplink datapath tick (40G: 4 pkt/tick) -> FC, through
-        # the same shared switch-step kernel (single component).
-        (csw_up_q, cserve, _, _, csw_drop, csw_wait, csw_m1,
-         csw_m2) = ops.switch_step(
-            state.csw_up_q, state.csw_gate.stage, inter_in,
-            state.csw_gate.draining, valid=csw_valid[:, None] & csw_ok,
-            cap=scen.queue_cap, hi=scen.hi, lo=scen.lo, serve_rate=4.0)
-        acc["drops"] += jnp.sum(csw_drop)
-        acc["csw_up_backlog"] += jnp.sum(state.csw_up_q)
-        acc["csw_up_served"] += jnp.sum(cserve)
-        acc["csw_occ_m1"] += jnp.sum(csw_m1)
-        acc["csw_occ_m2"] += jnp.sum(csw_m2)
+            # 5. CSW uplink datapath tick (40G: 4 pkt/tick) -> FC, through
+            # the same shared switch-step kernel (single component).
+            (csw_up_q, cserve, _, _, csw_drop, csw_wait, csw_m1,
+             csw_m2) = ops.switch_step(
+                state.csw_up_q, state.csw_gate.stage, inter_in,
+                state.csw_gate.draining, valid=csw_valid[:, None] & csw_ok,
+                cap=scen.queue_cap, hi=scen.hi, lo=scen.lo, serve_rate=4.0)
+            acc["drops"] += jnp.sum(csw_drop)
+            acc["csw_up_backlog"] += jnp.sum(state.csw_up_q)
+            acc["csw_up_served"] += jnp.sum(cserve)
+            acc["csw_occ_m1"] += jnp.sum(csw_m1)
+            acc["csw_occ_m2"] += jnp.sum(csw_m2)
 
-        # uplink f of csw c lands on FC f (the csw_uplinks axis; == n_fc
-        # by the FBSite invariant). The FC routes traffic for cluster k
-        # down an ACTIVE (f, c') plane of that cluster (per-stage CAMs):
-        # weight by the cluster's csw-uplink activity and by the dest
-        # rack's active planes.
-        fc_in = jnp.sum(cserve, axis=0)                          # (CUP,)
-        csw_stage_f = state.csw_gate.stage.astype(jnp.float32)
-        fc_w = (jnp.arange(CUP)[None, :]
-                < state.csw_gate.stage[:, None]) / csw_stage_f[:, None]
-        # csw c's share of its cluster's down traffic = how much of the
-        # cluster's REAL racks ride plane (c mod csw_per_cluster)
-        csw_share = (jnp.sum(plane_w_c, axis=1) / rpcf).reshape(NC)
-        # total inter-cluster down traffic splits uniformly over the
-        # REAL clusters
-        down_cl = jnp.sum(fc_in) / nclf                          # scalar
-        fc_down_add = down_cl * csw_share[None, :] * fc_w.T      # (NF,NC)
-        fc_down_q = state.fc_down_q + fc_down_add
+            # uplink f of csw c lands on FC f (the csw_uplinks axis; == n_fc
+            # by the FBSite invariant). The FC routes traffic for cluster k
+            # down an ACTIVE (f, c') plane of that cluster (per-stage CAMs):
+            # weight by the cluster's csw-uplink activity and by the dest
+            # rack's active planes.
+            fc_in = jnp.sum(cserve, axis=0)                          # (CUP,)
+            csw_stage_f = state.csw_gate.stage.astype(jnp.float32)
+            fc_w = (jnp.arange(CUP)[None, :]
+                    < state.csw_gate.stage[:, None]) / csw_stage_f[:, None]
+            # csw c's share of its cluster's down traffic = how much of the
+            # cluster's REAL racks ride plane (c mod csw_per_cluster)
+            csw_share = (jnp.sum(plane_w_c, axis=1) / rpcf).reshape(NC)
+            # total inter-cluster down traffic splits uniformly over the
+            # REAL clusters
+            down_cl = jnp.sum(fc_in) / nclf                          # scalar
+            fc_down_add = down_cl * csw_share[None, :] * fc_w.T      # (NF,NC)
+            fc_down_q = state.fc_down_q + fc_down_add
 
-        # 6. FC down serve: link (f,c) active iff csw stage[c] > f AND
-        #    csw c's uplink-f transceiver is healthy (it is the same
-        #    fiber); any residual on an inactive/dead plane rides the
-        #    FC ring to the always-on f=0 plane.
-        fc_active = (jnp.arange(NF)[:, None]
-                     < state.csw_gate.stage[None, :]) & csw_ok.T  # (NF,NC)
-        fserve = jnp.minimum(fc_down_q, 4.0) * fc_active
-        fc_down_q = fc_down_q - fserve
-        stranded = jnp.where(~fc_active, fc_down_q, 0.0)
-        mig = jnp.minimum(jnp.sum(stranded), scen.fc_ring)
-        # <= 1 under exact division; clamped because a TPU's is not (an
-        # ulp over 1 moves more than is stranded and leaves a negative
-        # backlog, see kernels/ref.py) — a no-op on the CPU
-        mfrac = jnp.minimum(mig / jnp.maximum(jnp.sum(stranded), 1e-9),
-                            1.0)
-        fc_down_q = fc_down_q - stranded * mfrac
-        fc_down_q = fc_down_q.at[0, :].add(
-            jnp.sum(stranded * mfrac, axis=0))
-        acc["fc_ring_pkts"] += mig
-        acc["fc_backlog"] += jnp.sum(state.fc_down_q)
-        acc["fc_served"] += jnp.sum(fserve)
+        with jax.named_scope("down"):
+            # 6. FC down serve: link (f,c) active iff csw stage[c] > f AND
+            #    csw c's uplink-f transceiver is healthy (it is the same
+            #    fiber); any residual on an inactive/dead plane rides the
+            #    FC ring to the always-on f=0 plane.
+            fc_active = (jnp.arange(NF)[:, None]
+                         < state.csw_gate.stage[None, :]) & csw_ok.T  # (NF,NC)
+            fserve = jnp.minimum(fc_down_q, 4.0) * fc_active
+            fc_down_q = fc_down_q - fserve
+            stranded = jnp.where(~fc_active, fc_down_q, 0.0)
+            mig = jnp.minimum(jnp.sum(stranded), scen.fc_ring)
+            # <= 1 under exact division; clamped because a TPU's is not (an
+            # ulp over 1 moves more than is stranded and leaves a negative
+            # backlog, see kernels/ref.py) — a no-op on the CPU
+            mfrac = jnp.minimum(mig / jnp.maximum(jnp.sum(stranded), 1e-9),
+                                1.0)
+            fc_down_q = fc_down_q - stranded * mfrac
+            fc_down_q = fc_down_q.at[0, :].add(
+                jnp.sum(stranded * mfrac, axis=0))
+            acc["fc_ring_pkts"] += mig
+            acc["fc_backlog"] += jnp.sum(state.fc_down_q)
+            acc["fc_served"] += jnp.sum(fserve)
 
-        # FC-served packets land on csw c -> its down queues, weighted by
-        # each rack's active planes (stage-aware, as above)
-        per_csw_down = jnp.sum(fserve, axis=0)                   # (NC,)
-        pw_cr = plane_w_c.transpose(0, 2, 1).reshape(NC, RPC)    # (NC,RPC)
-        row_w = jnp.sum(pw_cr, axis=1)                           # (NC,)
-        pw_norm = pw_cr / jnp.maximum(row_w[:, None], 1e-9)
-        routable = row_w > 0.0
-        csw_down_q = csw_down_q + \
-            jnp.where(routable, per_csw_down, 0.0)[:, None] * pw_norm
-        # a csw can still drain FC backlog for a plane no rack currently
-        # rides (every rack staged below it after the queue built up);
-        # that traffic rides the cluster ring to the always-on plane 0
-        # rather than vanishing (conservation: injected == delivered +
-        # in-flight + drops)
-        orphan = jnp.where(routable, 0.0, per_csw_down)          # (NC,)
-        orphan_cl = jnp.sum(orphan.reshape(NCL, P), axis=1)      # (NCL,)
-        dest0 = pw_norm.reshape(NCL, P, RPC)[:, 0, :]            # (NCL,RPC)
-        csw_down_q = (csw_down_q.reshape(NCL, P, RPC)
-                      .at[:, 0, :].add(orphan_cl[:, None] * dest0)
-                      .reshape(NC, RPC))
-        acc["ring_pkts"] += jnp.sum(orphan_cl)
+            # FC-served packets land on csw c -> its down queues, weighted by
+            # each rack's active planes (stage-aware, as above)
+            per_csw_down = jnp.sum(fserve, axis=0)                   # (NC,)
+            pw_cr = plane_w_c.transpose(0, 2, 1).reshape(NC, RPC)    # (NC,RPC)
+            row_w = jnp.sum(pw_cr, axis=1)                           # (NC,)
+            pw_norm = pw_cr / jnp.maximum(row_w[:, None], 1e-9)
+            routable = row_w > 0.0
+            csw_down_q = csw_down_q + \
+                jnp.where(routable, per_csw_down, 0.0)[:, None] * pw_norm
+            # a csw can still drain FC backlog for a plane no rack currently
+            # rides (every rack staged below it after the queue built up);
+            # that traffic rides the cluster ring to the always-on plane 0
+            # rather than vanishing (conservation: injected == delivered +
+            # in-flight + drops)
+            orphan = jnp.where(routable, 0.0, per_csw_down)          # (NC,)
+            orphan_cl = jnp.sum(orphan.reshape(NCL, P), axis=1)      # (NCL,)
+            dest0 = pw_norm.reshape(NCL, P, RPC)[:, 0, :]        # (NCL,RPC)
+            csw_down_q = (csw_down_q.reshape(NCL, P, RPC)
+                          .at[:, 0, :].add(orphan_cl[:, None] * dest0)
+                          .reshape(NC, RPC))
+            acc["ring_pkts"] += jnp.sum(orphan_cl)
 
-        # 7. CSW down serve: link (r, c) active iff rsw stage[r] > c AND
-        #    rack r's uplink-c transceiver is healthy (same fiber) —
-        #    the plane axis is csw_per_cluster; stranded traffic rides
-        #    the cluster ring to c=0.
-        rsw_stage = state.rsw_gate.stage.reshape(NCL, RPC)
-        rsw_ok_pl = rsw_ok.reshape(NCL, RPC, P) \
-            .transpose(0, 2, 1)                                  # (NCL,P,RPC)
-        cidx = jnp.arange(P)[None, :, None]                      # plane pos
-        down_act = (cidx < rsw_stage[:, None, :]) & rsw_ok_pl    # (NCL,P,RPC)
-        dq = csw_down_q.reshape(NCL, P, RPC)
-        dserve = jnp.minimum(dq, 1.0) * down_act
-        dq = dq - dserve
-        stranded_d = jnp.where(~down_act, dq, 0.0)               # (NCL,P,RPC)
-        tot_str = jnp.sum(stranded_d, axis=(1, 2))               # (NCL,)
-        migd = jnp.minimum(tot_str, scen.csw_ring)
-        dfrac = jnp.minimum(migd / jnp.maximum(tot_str, 1e-9),
-                            1.0)[:, None, None]   # clamped as mfrac
-        moved = stranded_d * dfrac
-        dq = dq - moved
-        dq = dq.at[:, 0, :].add(jnp.sum(moved, axis=1))
-        csw_down_q = dq.reshape(NC, RPC)
-        acc["ring_pkts"] += jnp.sum(migd)
-        acc["csw_down_backlog"] += jnp.sum(state.csw_down_q)
-        delivered_r = jnp.sum(dserve, axis=1).reshape(R)         # (R,)
-        acc["csw_down_served"] += jnp.sum(dserve)
+            # 7. CSW down serve: link (r, c) active iff rsw stage[r] > c AND
+            #    rack r's uplink-c transceiver is healthy (same fiber) —
+            #    the plane axis is csw_per_cluster; stranded traffic rides
+            #    the cluster ring to c=0.
+            rsw_stage = state.rsw_gate.stage.reshape(NCL, RPC)
+            rsw_ok_pl = rsw_ok.reshape(NCL, RPC, P) \
+                .transpose(0, 2, 1)                              # (NCL,P,RPC)
+            cidx = jnp.arange(P)[None, :, None]                  # plane pos
+            down_act = (cidx < rsw_stage[:, None, :]) & rsw_ok_pl # (NCL,P,RPC)
+            dq = csw_down_q.reshape(NCL, P, RPC)
+            dserve = jnp.minimum(dq, 1.0) * down_act
+            dq = dq - dserve
+            stranded_d = jnp.where(~down_act, dq, 0.0)           # (NCL,P,RPC)
+            tot_str = jnp.sum(stranded_d, axis=(1, 2))               # (NCL,)
+            migd = jnp.minimum(tot_str, scen.csw_ring)
+            dfrac = jnp.minimum(migd / jnp.maximum(tot_str, 1e-9),
+                                1.0)[:, None, None]   # clamped as mfrac
+            moved = stranded_d * dfrac
+            dq = dq - moved
+            dq = dq.at[:, 0, :].add(jnp.sum(moved, axis=1))
+            csw_down_q = dq.reshape(NC, RPC)
+            acc["ring_pkts"] += jnp.sum(migd)
+            acc["csw_down_backlog"] += jnp.sum(state.csw_down_q)
+            delivered_r = jnp.sum(dserve, axis=1).reshape(R)         # (R,)
+            acc["csw_down_served"] += jnp.sum(dserve)
 
-        # 8. node-level link gating (OS intercept: zero latency cost).
-        # A server link is held on while its server has active flows (tx)
-        # or receives traffic, with an idle timeout.
-        need = jnp.minimum(n_holding + delivered_r, scen.spr)
-        node_on = jnp.maximum(
-            need, state.node_on - scen.spr / NODE_IDLE_TICKS)
-        acc["node_on"] += jnp.sum(node_on)
+        with jax.named_scope("stats"):
+            # 8. node-level link gating (OS intercept: zero latency cost).
+            # A server link is held on while its server has active flows (tx)
+            # or receives traffic, with an idle timeout.
+            need = jnp.minimum(n_holding + delivered_r, scen.spr)
+            node_on = jnp.maximum(
+                need, state.node_on - scen.spr / NODE_IDLE_TICKS)
+            acc["node_on"] += jnp.sum(node_on)
 
-        # 8.5 in-scan delay sampling (see module docstring): one sample
-        # per rack per destination class for the packets injected THIS
-        # tick, fed by the kernel's backlog-age taps plus the
-        # gating-attributed wake stall. (R, planes) view of the CSW down
-        # queues each rack faces — shared with the step-9 RSW trigger.
-        down_rc = csw_down_q.reshape(NCL, P, RPC) \
-            .transpose(0, 2, 1).reshape(R, P)                # (R, planes)
-        # queue waits: RSW enqueue (kernel), CSW down plane-weighted
-        # (1 pkt/tick links), CSW uplink arrival-weighted per cluster,
-        # FC capacity-normalized (4 pkt/tick per active real link)
-        down_wait = jnp.sum(plane_w * down_rc, axis=1)           # (R,)
-        win = inter_in.reshape(NCL, P)
+            # 8.5 in-scan delay sampling (see module docstring): one sample
+            # per rack per destination class for the packets injected THIS
+            # tick, fed by the kernel's backlog-age taps plus the
+            # gating-attributed wake stall. (R, planes) view of the CSW down
+            # queues each rack faces — shared with the step-9 RSW trigger.
+            down_rc = csw_down_q.reshape(NCL, P, RPC) \
+                .transpose(0, 2, 1).reshape(R, P)                # (R, planes)
+            # queue waits: RSW enqueue (kernel), CSW down plane-weighted
+            # (1 pkt/tick links), CSW uplink arrival-weighted per cluster,
+            # FC capacity-normalized (4 pkt/tick per active real link)
+            down_wait = jnp.sum(plane_w * down_rc, axis=1)           # (R,)
+            win = inter_in.reshape(NCL, P)
 
-        def cl_avg(x):
-            # arrival-weighted per-cluster mean over the cluster's CSWs
-            return jnp.sum(win * x.reshape(NCL, P), axis=1) \
-                / jnp.maximum(jnp.sum(win, axis=1), 1e-9)        # (NCL,)
+            def cl_avg(x):
+                # arrival-weighted per-cluster mean over the cluster's CSWs
+                return jnp.sum(win * x.reshape(NCL, P), axis=1) \
+                    / jnp.maximum(jnp.sum(win, axis=1), 1e-9)        # (NCL,)
 
-        w_csw_cl = cl_avg(csw_wait)
-        fc_cap = 4.0 * jnp.sum((fc_active & csw_valid[None, :])
-                               .astype(jnp.float32))
-        fc_wait = jnp.sum(fc_down_q) / jnp.maximum(fc_cap, 1e-9)
-        # wake stalls: remaining STAGE_UP_DELAY ticks of an in-flight
-        # stage-up at the switches this rack's packets traverse; exactly
-        # zero with gating disabled (up_timer never leaves 0, and the
-        # attribution is masked besides)
-        # wake + fault-forced stalls through the ONE attribution seam
-        # (gating.stall_attribution): the same pair feeds the delay
-        # histogram below AND the flow FCT samples, so gating stalls
-        # attribute into flow completion times by construction; both
-        # are EXACTLY 0 when gating is off
-        g_on = scen.gating_enabled
-        stall_rsw, fstall_rsw = gating.stall_attribution(
-            state.rsw_gate, state.rsw_fault, g_on)               # (R,)
-        stall_csw, fstall_csw = gating.stall_attribution(
-            state.csw_gate, state.csw_fault, g_on)               # (NC,)
-        stall_csw_cl = cl_avg(stall_csw)
-        fstall_csw_cl = cl_avg(fstall_csw)
+            w_csw_cl = cl_avg(csw_wait)
+            fc_cap = 4.0 * jnp.sum((fc_active & csw_valid[None, :])
+                                   .astype(jnp.float32))
+            fc_wait = jnp.sum(fc_down_q) / jnp.maximum(fc_cap, 1e-9)
+            # wake stalls: remaining STAGE_UP_DELAY ticks of an in-flight
+            # stage-up at the switches this rack's packets traverse; exactly
+            # zero with gating disabled (up_timer never leaves 0, and the
+            # attribution is masked besides)
+            # wake + fault-forced stalls through the ONE attribution seam
+            # (gating.stall_attribution): the same pair feeds the delay
+            # histogram below AND the flow FCT samples, so gating stalls
+            # attribute into flow completion times by construction; both
+            # are EXACTLY 0 when gating is off
+            g_on = scen.gating_enabled
+            stall_rsw, fstall_rsw = gating.stall_attribution(
+                state.rsw_gate, state.rsw_fault, g_on)               # (R,)
+            stall_csw, fstall_csw = gating.stall_attribution(
+                state.csw_gate, state.csw_fault, g_on)               # (NC,)
+            stall_csw_cl = cl_avg(stall_csw)
+            fstall_csw_cl = cl_avg(fstall_csw)
 
-        def per_rack(x_cl):                                      # (NCL,)->(R,)
-            return jnp.broadcast_to(x_cl[:, None], (NCL, RPC)).reshape(R)
+            def per_rack(x_cl):                                  # (NCL,)->(R,)
+                return jnp.broadcast_to(x_cl[:, None], (NCL, RPC)).reshape(R)
 
-        wt_i, wt_x = by_dest[:, 1], by_dest[:, 2]      # intra-cl / inter
-        q_i = rsw_wait + down_wait                     # queue-wait parts
-        q_x = q_i + per_rack(w_csw_cl) + fc_wait
-        s_i = stall_rsw                                # wake-stall parts
-        s_x = stall_rsw + per_rack(stall_csw_cl)
-        f_i = fstall_rsw                               # fault-stall parts
-        f_x = fstall_rsw + per_rack(fstall_csw_cl)
-        base_i = STACK_US + 4.0 * WIRE_HOP_US
-        d_i = base_i + q_i + s_i + f_i
-        d_x = base_i + 2.0 * WIRE_HOP_US + q_x + s_x + f_x
-        hist = _delay_hist_add(acc["delay_hist"], d_i, wt_i)
-        acc["delay_hist"] = _delay_hist_add(hist, d_x, wt_x)
-        acc["delay_sum"] += jnp.sum(wt_i * d_i) + jnp.sum(wt_x * d_x)
-        acc["delay_wt"] += jnp.sum(wt_i) + jnp.sum(wt_x)
-        acc["delay_wt_inter"] += jnp.sum(wt_x)
-        acc["delay_queue_sum"] += jnp.sum(wt_i * q_i) + jnp.sum(wt_x * q_x)
-        acc["delay_stall_sum"] += jnp.sum(wt_i * s_i) + jnp.sum(wt_x * s_x)
-        acc["delay_fault_sum"] += jnp.sum(wt_i * f_i) + jnp.sum(wt_x * f_x)
-        acc["wake_stall_pkts"] += jnp.sum(wt_i * (s_i > 0)) \
-            + jnp.sum(wt_x * (s_x > 0))
-        acc["fault_stall_pkts"] += jnp.sum(wt_i * (f_i > 0)) \
-            + jnp.sum(wt_x * (f_x > 0))
+            wt_i, wt_x = by_dest[:, 1], by_dest[:, 2]      # intra-cl / inter
+            q_i = rsw_wait + down_wait                     # queue-wait parts
+            q_x = q_i + per_rack(w_csw_cl) + fc_wait
+            s_i = stall_rsw                                # wake-stall parts
+            s_x = stall_rsw + per_rack(stall_csw_cl)
+            f_i = fstall_rsw                               # fault-stall parts
+            f_x = fstall_rsw + per_rack(fstall_csw_cl)
+            base_i = STACK_US + 4.0 * WIRE_HOP_US
+            d_i = base_i + q_i + s_i + f_i
+            d_x = base_i + 2.0 * WIRE_HOP_US + q_x + s_x + f_x
+            hist = _delay_hist_add(acc["delay_hist"], d_i, wt_i)
+            acc["delay_hist"] = _delay_hist_add(hist, d_x, wt_x)
+            acc["delay_sum"] += jnp.sum(wt_i * d_i) + jnp.sum(wt_x * d_x)
+            acc["delay_wt"] += jnp.sum(wt_i) + jnp.sum(wt_x)
+            acc["delay_wt_inter"] += jnp.sum(wt_x)
+            acc["delay_queue_sum"] += jnp.sum(wt_i * q_i) + jnp.sum(wt_x * q_x)
+            acc["delay_stall_sum"] += jnp.sum(wt_i * s_i) + jnp.sum(wt_x * s_x)
+            acc["delay_fault_sum"] += jnp.sum(wt_i * f_i) + jnp.sum(wt_x * f_x)
+            acc["wake_stall_pkts"] += jnp.sum(wt_i * (s_i > 0)) \
+                + jnp.sum(wt_x * (s_x > 0))
+            acc["fault_stall_pkts"] += jnp.sum(wt_i * (f_i > 0)) \
+                + jnp.sum(wt_x * (f_x > 0))
 
-        # 8.6 flow completion times (flow_mode=1; every weight below is
-        # exactly 0 at flow_mode=0). FCT = table residence + THIS
-        # tick's sampled path delay for the flow's class — d_i/d_x
-        # already carry queue waits plus the wake/fault stalls, so
-        # gating stalls attribute into FCT through the same seam as the
-        # delay histogram. Slowdown is vs the ideal-bandwidth baseline
-        # (line-rate serialization + unloaded path); residence >= size
-        # (per-tick emission <= line rate) and path >= the unloaded
-        # path, so slowdown >= 1 by construction.
-        wdone = done.astype(jnp.float32)                     # (R,FT)
-        residence = (tick_now - ft_start + 1).astype(jnp.float32)
-        path_us = jnp.where(
-            ft_dst == 2, d_x[:, None],
-            jnp.where(ft_dst == 1, d_i[:, None], STACK_US))
-        fct_us = residence * C.TICK_US + path_us
-        ideal_base = jnp.where(
-            ft_dst == 2, base_i + 2.0 * WIRE_HOP_US,
-            jnp.where(ft_dst == 1, base_i, STACK_US))
-        ideal_us = workloads.ideal_fct_us(ft_size, ideal_base)
-        slow = fct_us / ideal_us
-        cls = workloads.flow_size_class(ft_size)             # (R,FT)
-        fct_flat = fct_us.reshape(-1)
-        slow_flat = slow.reshape(-1)
-        for c in range(3):
-            wc = (wdone * (cls == c)).reshape(-1)
-            acc["fct_hist"] = acc["fct_hist"].at[c].set(
-                _delay_hist_add(
-                    acc["fct_hist"][c], fct_flat, wc,
-                    min_val=C.FCT_HIST_MIN_US,
-                    bpo=C.FCT_HIST_BINS_PER_OCTAVE,
-                    bins=C.FCT_HIST_BINS))
-            acc["fct_slow_hist"] = acc["fct_slow_hist"].at[c].set(
-                _delay_hist_add(
-                    acc["fct_slow_hist"][c], slow_flat, wc,
-                    min_val=C.FCT_SLOWDOWN_HIST_MIN,
-                    bpo=C.FCT_SLOWDOWN_HIST_BINS_PER_OCTAVE,
-                    bins=C.FCT_SLOWDOWN_HIST_BINS))
-        acc["flows_completed"] += jnp.sum(wdone)
-        acc["fct_sum"] += jnp.sum(fct_us * wdone)
-        acc["fct_slow_sum"] += jnp.sum(slow * wdone)
+            # 8.6 flow completion times (flow_mode=1; every weight below is
+            # exactly 0 at flow_mode=0). FCT = table residence + THIS
+            # tick's sampled path delay for the flow's class — d_i/d_x
+            # already carry queue waits plus the wake/fault stalls, so
+            # gating stalls attribute into FCT through the same seam as the
+            # delay histogram. Slowdown is vs the ideal-bandwidth baseline
+            # (line-rate serialization + unloaded path); residence >= size
+            # (per-tick emission <= line rate) and path >= the unloaded
+            # path, so slowdown >= 1 by construction.
+            wdone = done.astype(jnp.float32)                     # (R,FT)
+            residence = (tick_now - ft_start + 1).astype(jnp.float32)
+            path_us = jnp.where(
+                ft_dst == 2, d_x[:, None],
+                jnp.where(ft_dst == 1, d_i[:, None], STACK_US))
+            fct_us = residence * C.TICK_US + path_us
+            ideal_base = jnp.where(
+                ft_dst == 2, base_i + 2.0 * WIRE_HOP_US,
+                jnp.where(ft_dst == 1, base_i, STACK_US))
+            ideal_us = workloads.ideal_fct_us(ft_size, ideal_base)
+            slow = fct_us / ideal_us
+            cls = workloads.flow_size_class(ft_size)             # (R,FT)
+            fct_flat = fct_us.reshape(-1)
+            slow_flat = slow.reshape(-1)
+            for c in range(3):
+                wc = (wdone * (cls == c)).reshape(-1)
+                acc["fct_hist"] = acc["fct_hist"].at[c].set(
+                    _delay_hist_add(
+                        acc["fct_hist"][c], fct_flat, wc,
+                        min_val=C.FCT_HIST_MIN_US,
+                        bpo=C.FCT_HIST_BINS_PER_OCTAVE,
+                        bins=C.FCT_HIST_BINS))
+                acc["fct_slow_hist"] = acc["fct_slow_hist"].at[c].set(
+                    _delay_hist_add(
+                        acc["fct_slow_hist"][c], slow_flat, wc,
+                        min_val=C.FCT_SLOWDOWN_HIST_MIN,
+                        bpo=C.FCT_SLOWDOWN_HIST_BINS_PER_OCTAVE,
+                        bins=C.FCT_SLOWDOWN_HIST_BINS))
+            acc["flows_completed"] += jnp.sum(wdone)
+            acc["fct_sum"] += jnp.sum(fct_us * wdone)
+            acc["fct_slow_sum"] += jnp.sum(slow * wdone)
 
-        # 9. watermark controllers. Per Sec III-B the backlog monitor
-        # watches ALL output queues of a switch: the RSW trigger combines
-        # its uplink queues with the CSW down-queue pressure on each
-        # plane-to-rack link, and the CSW trigger combines its FC uplink
-        # queues with the FC down-queue pressure per plane (a saturated
-        # 40G down plane must open the next stage). gating_enabled is a
-        # traced scenario knob: the controller always steps and the
-        # result is selected, so LC/DC and always-on scenarios share one
-        # compiled program. max_stage caps each switch at its REAL link
-        # count (padded hull links never activate).
-        #
-        # 9a. hard-fault evolution FIRST (applies to LC/DC and always-on
-        # scenarios alike: transceivers die regardless of the
-        # controller): Bernoulli arrivals on powered healthy real links,
-        # repair countdown, and the dying link's queued packets move to
-        # the fault-drop conservation bin (a dead laser transmits
-        # nothing; injected == delivered + in-flight + drops +
-        # fault_drops stays exact).
-        rsw_timer, rsw_new_f = gating.fault_arrivals(
-            state.rsw_fault.timer, u_fr[:, 2:2 + P],
-            state.rsw_gate.powered, rsw_link_real,
-            scen.fault_prob, scen.repair_ticks,
-            plane_u=u_plane_r, plane_fail_prob=scen.plane_fail_prob)
-        csw_timer, csw_new_f = gating.fault_arrivals(
-            state.csw_fault.timer, u_fc[:, 2:2 + CUP],
-            state.csw_gate.powered, csw_link_real,
-            scen.fault_prob, scen.repair_ticks,
-            plane_u=u_plane_c, plane_fail_prob=scen.plane_fail_prob)
-        acc["fault_drops"] += \
-            jnp.sum(jnp.where(rsw_new_f[..., None], rsw_q, 0.0)) \
-            + jnp.sum(jnp.where(csw_new_f, csw_up_q, 0.0))
-        rsw_q = jnp.where(rsw_new_f[..., None], 0.0, rsw_q)
-        csw_up_q = jnp.where(csw_new_f, 0.0, csw_up_q)
-        acc["fault_link_ticks"] += jnp.sum(rsw_timer > 0) \
-            + jnp.sum(csw_timer > 0)
+        with jax.named_scope("gating"):
+            # 9. watermark controllers. Per Sec III-B the backlog monitor
+            # watches ALL output queues of a switch: the RSW trigger combines
+            # its uplink queues with the CSW down-queue pressure on each
+            # plane-to-rack link, and the CSW trigger combines its FC uplink
+            # queues with the FC down-queue pressure per plane (a saturated
+            # 40G down plane must open the next stage). gating_enabled is a
+            # traced scenario knob: the controller always steps and the
+            # result is selected, so LC/DC and always-on scenarios share one
+            # compiled program. max_stage caps each switch at its REAL link
+            # count (padded hull links never activate).
+            #
+            # 9a. hard-fault evolution FIRST (applies to LC/DC and always-on
+            # scenarios alike: transceivers die regardless of the
+            # controller): Bernoulli arrivals on powered healthy real links,
+            # repair countdown, and the dying link's queued packets move to
+            # the fault-drop conservation bin (a dead laser transmits
+            # nothing; injected == delivered + in-flight + drops +
+            # fault_drops stays exact).
+            rsw_timer, rsw_new_f = gating.fault_arrivals(
+                state.rsw_fault.timer, u_fr[:, 2:2 + P],
+                state.rsw_gate.powered, rsw_link_real,
+                scen.fault_prob, scen.repair_ticks,
+                plane_u=u_plane_r, plane_fail_prob=scen.plane_fail_prob)
+            csw_timer, csw_new_f = gating.fault_arrivals(
+                state.csw_fault.timer, u_fc[:, 2:2 + CUP],
+                state.csw_gate.powered, csw_link_real,
+                scen.fault_prob, scen.repair_ticks,
+                plane_u=u_plane_c, plane_fail_prob=scen.plane_fail_prob)
+            acc["fault_drops"] += \
+                jnp.sum(jnp.where(rsw_new_f[..., None], rsw_q, 0.0)) \
+                + jnp.sum(jnp.where(csw_new_f, csw_up_q, 0.0))
+            rsw_q = jnp.where(rsw_new_f[..., None], 0.0, rsw_q)
+            csw_up_q = jnp.where(csw_new_f, 0.0, csw_up_q)
+            acc["fault_link_ticks"] += jnp.sum(rsw_timer > 0) \
+                + jnp.sum(csw_timer > 0)
 
-        # 9b. the controllers, fault-aware: jittered/failing wakes plus
-        # the min-connectivity fallback (force-wake the cheapest healthy
-        # link when the usable prefix died; stall charged to the
-        # fault_stall carry). All knobs zero => bit-identical GateState.
-        rsw_gated, rsw_fwake, rsw_diag = gating.gate_step(
-            state.rsw_gate, jnp.maximum(jnp.sum(rsw_q, axis=2), down_rc),
-            cap=scen.queue_cap, hi=scen.hi, lo=scen.lo, dwell=scen.dwell,
-            max_stage=rsw_max, link_ok=rsw_timer == 0,
-            link_real=rsw_link_real, u_jitter=u_fr[:, 0],
-            u_fail=u_fr[:, 1], wake_fail_prob=scen.wake_fail_prob,
-            wake_jitter_frac=scen.wake_jitter_frac,
-            fault_wake=state.rsw_fault.wake,
-            fallback=scen.fault_fallback)
-        csw_gated, csw_fwake, csw_diag = gating.gate_step(
-            state.csw_gate, jnp.maximum(csw_up_q, fc_down_q.T),
-            cap=scen.queue_cap, hi=scen.hi, lo=scen.lo, dwell=scen.dwell,
-            max_stage=csw_max, link_ok=csw_timer == 0,
-            link_real=csw_link_real, u_jitter=u_fc[:, 0],
-            u_fail=u_fc[:, 1], wake_fail_prob=scen.wake_fail_prob,
-            wake_jitter_frac=scen.wake_jitter_frac,
-            fault_wake=state.csw_fault.wake,
-            fallback=scen.fault_fallback)
+            # 9b. the controllers, fault-aware: jittered/failing wakes plus
+            # the min-connectivity fallback (force-wake the cheapest healthy
+            # link when the usable prefix died; stall charged to the
+            # fault_stall carry). All knobs zero => bit-identical GateState.
+            rsw_gated, rsw_fwake, rsw_diag = gating.gate_step(
+                state.rsw_gate, jnp.maximum(jnp.sum(rsw_q, axis=2), down_rc),
+                cap=scen.queue_cap, hi=scen.hi, lo=scen.lo, dwell=scen.dwell,
+                max_stage=rsw_max, link_ok=rsw_timer == 0,
+                link_real=rsw_link_real, u_jitter=u_fr[:, 0],
+                u_fail=u_fr[:, 1], wake_fail_prob=scen.wake_fail_prob,
+                wake_jitter_frac=scen.wake_jitter_frac,
+                fault_wake=state.rsw_fault.wake,
+                fallback=scen.fault_fallback)
+            csw_gated, csw_fwake, csw_diag = gating.gate_step(
+                state.csw_gate, jnp.maximum(csw_up_q, fc_down_q.T),
+                cap=scen.queue_cap, hi=scen.hi, lo=scen.lo, dwell=scen.dwell,
+                max_stage=csw_max, link_ok=csw_timer == 0,
+                link_real=csw_link_real, u_jitter=u_fc[:, 0],
+                u_fail=u_fc[:, 1], wake_fail_prob=scen.wake_fail_prob,
+                wake_jitter_frac=scen.wake_jitter_frac,
+                fault_wake=state.csw_fault.wake,
+                fallback=scen.fault_fallback)
 
-        def sel(new, old):
-            return jax.tree.map(
-                lambda a, b: jnp.where(scen.gating_enabled, a, b), new, old)
+            def sel(new, old):
+                return jax.tree.map(
+                    lambda a, b: jnp.where(scen.gating_enabled, a, b),
+                    new, old)
 
-        rsw_gate = sel(rsw_gated, state.rsw_gate)
-        csw_gate = sel(csw_gated, state.csw_gate)
-        # the fallback (and its stall) only exists under gating: an
-        # always-on scenario's links are already all up, so the carry is
-        # pinned to 0 — fault_stall attribution exactly 0, as pinned by
-        # the acceptance tests
-        rsw_fwake = jnp.where(g_on, rsw_fwake, 0)
-        csw_fwake = jnp.where(g_on, csw_fwake, 0)
-        acc["wake_retries"] += jnp.where(
-            g_on, jnp.sum(rsw_diag["retries"]) +
-            jnp.sum(csw_diag["retries"]), 0)
-        acc["forced_wakes"] += jnp.where(
-            g_on, jnp.sum(rsw_diag["forced"]) +
-            jnp.sum(csw_diag["forced"]), 0)
+            rsw_gate = sel(rsw_gated, state.rsw_gate)
+            csw_gate = sel(csw_gated, state.csw_gate)
+            # the fallback (and its stall) only exists under gating: an
+            # always-on scenario's links are already all up, so the carry is
+            # pinned to 0 — fault_stall attribution exactly 0, as pinned by
+            # the acceptance tests
+            rsw_fwake = jnp.where(g_on, rsw_fwake, 0)
+            csw_fwake = jnp.where(g_on, csw_fwake, 0)
+            acc["wake_retries"] += jnp.where(
+                g_on, jnp.sum(rsw_diag["retries"]) +
+                jnp.sum(csw_diag["retries"]), 0)
+            acc["forced_wakes"] += jnp.where(
+                g_on, jnp.sum(rsw_diag["forced"]) +
+                jnp.sum(csw_diag["forced"]), 0)
 
-        # 9c. min-connectivity audit on the END-of-tick state: a valid
-        # switch that still HAS a healthy real link but zero usable
-        # ones records a connectivity-loss tick — loss attributable to
-        # the gating policy (links powered off), which the fallback
-        # must drive to exactly 0. A switch whose real links are ALL
-        # simultaneously hard-faulted is excluded: no routing/gating
-        # policy can preserve its connectivity, and that hardware
-        # unavailability is already visible in link_fault_frac /
-        # delivered_frac.
-        rsw_healthy = (rsw_timer == 0) & rsw_link_real
-        csw_healthy = (csw_timer == 0) & csw_link_real
-        rsw_usable_f = gating.usable_links(rsw_gate.stage,
-                                           rsw_gate.draining, P) \
-            & rsw_healthy
-        csw_usable_f = gating.usable_links(csw_gate.stage,
-                                           csw_gate.draining, CUP) \
-            & csw_healthy
-        acc["conn_loss_rack_ticks"] += jnp.sum(
-            rack_valid & jnp.any(rsw_healthy, axis=1)
-            & ~jnp.any(rsw_usable_f, axis=1))
-        acc["conn_loss_csw_ticks"] += jnp.sum(
-            csw_valid & jnp.any(csw_healthy, axis=1)
-            & ~jnp.any(csw_usable_f, axis=1))
+            # 9c. min-connectivity audit on the END-of-tick state: a valid
+            # switch that still HAS a healthy real link but zero usable
+            # ones records a connectivity-loss tick — loss attributable to
+            # the gating policy (links powered off), which the fallback
+            # must drive to exactly 0. A switch whose real links are ALL
+            # simultaneously hard-faulted is excluded: no routing/gating
+            # policy can preserve its connectivity, and that hardware
+            # unavailability is already visible in link_fault_frac /
+            # delivered_frac.
+            rsw_healthy = (rsw_timer == 0) & rsw_link_real
+            csw_healthy = (csw_timer == 0) & csw_link_real
+            rsw_usable_f = gating.usable_links(rsw_gate.stage,
+                                               rsw_gate.draining, P) \
+                & rsw_healthy
+            csw_usable_f = gating.usable_links(csw_gate.stage,
+                                               csw_gate.draining, CUP) \
+                & csw_healthy
+            acc["conn_loss_rack_ticks"] += jnp.sum(
+                rack_valid & jnp.any(rsw_healthy, axis=1)
+                & ~jnp.any(rsw_usable_f, axis=1))
+            acc["conn_loss_csw_ticks"] += jnp.sum(
+                csw_valid & jnp.any(csw_healthy, axis=1)
+                & ~jnp.any(csw_usable_f, axis=1))
 
-        # power accounting: a hard-faulted transceiver is dark — it
-        # draws nothing while dead, whatever the controller thinks
-        rsw_pow = jnp.sum(
-            jnp.where(rack_valid[:, None] & (rsw_timer == 0),
-                      rsw_gate.powered, False))
-        csw_pow = jnp.sum(
-            jnp.where(csw_valid[:, None] & (csw_timer == 0),
-                      csw_gate.powered, False))
-        acc["rsw_powered"] += rsw_pow
-        acc["csw_powered"] += csw_pow
-        # gated-link population of the REAL site:
-        # ncl*rpc*cpc (RSW-CSW) + ncl*cpc*nfc (CSW-FC)
-        cpcf = scen.cpc.astype(jnp.float32)
-        nfcf = scen.nfc.astype(jnp.float32)
-        n_gated = nclf * cpcf * (rpcf + nfcf)
-        frac_on = (rsw_pow + csw_pow) / n_gated
-        acc["half_off_ticks"] += (frac_on <= 0.5)
-        # half-open-LEFT quartiles (0,25],(25,50],(50,75],(75,100]: an
-        # exact boundary (e.g. the all-floor 25% state) belongs to the
-        # LOWER bucket, matching the histogram labels
-        bucket = on_frac_bucket(frac_on)
-        acc["on_frac_hist"] += (jnp.arange(4) == bucket)  # one-hot, no scatter
+            # power accounting: a hard-faulted transceiver is dark — it
+            # draws nothing while dead, whatever the controller thinks
+            rsw_pow = jnp.sum(
+                jnp.where(rack_valid[:, None] & (rsw_timer == 0),
+                          rsw_gate.powered, False))
+            csw_pow = jnp.sum(
+                jnp.where(csw_valid[:, None] & (csw_timer == 0),
+                          csw_gate.powered, False))
+            acc["rsw_powered"] += rsw_pow
+            acc["csw_powered"] += csw_pow
+            # gated-link population of the REAL site:
+            # ncl*rpc*cpc (RSW-CSW) + ncl*cpc*nfc (CSW-FC)
+            cpcf = scen.cpc.astype(jnp.float32)
+            nfcf = scen.nfc.astype(jnp.float32)
+            n_gated = nclf * cpcf * (rpcf + nfcf)
+            frac_on = (rsw_pow + csw_pow) / n_gated
+            acc["half_off_ticks"] += (frac_on <= 0.5)
+            # half-open-LEFT quartiles (0,25],(25,50],(50,75],(75,100]: an
+            # exact boundary (e.g. the all-floor 25% state) belongs to the
+            # LOWER bucket, matching the histogram labels
+            bucket = on_frac_bucket(frac_on)
+            # one-hot, no scatter
+            acc["on_frac_hist"] += (jnp.arange(4) == bucket)
 
         return SimState(key, burst_on, flow_rem, flow_dest, flow_fast,
                         tick_now, ft_start, ft_rem, ft_size, ft_dst,
@@ -1474,6 +1505,15 @@ class SweepValidationError(RuntimeError):
             f"sweep validation failed for scenario(s) {list(labels)} "
             f"(first failing chunk: {first_bad_chunk})")
 
+
+# The executor marks its phases with ``jax.profiler.TraceAnnotation``
+# host spans, which cost nothing until a profiler runs and then land in
+# the profile beside the device's ops, on one clock: ``lcdc.sweep``
+# (one ``run_sweep_planned`` call), ``lcdc.plan``, ``lcdc.batch``,
+# ``lcdc.init`` (the operands' build), ``lcdc.dispatch`` and one
+# ``lcdc.chunk`` per chunk, ``lcdc.snapshot``, ``lcdc.fetch`` (the host
+# waiting on the device), ``lcdc.finalize`` and ``lcdc.retry``.
+# chipbench/progtrace.py reads them.
 
 #: test hook for the fault-tolerant planned executor: when set, called
 #: as ``BUCKET_FAIL_HOOK(bucket_index, phase)`` with phase in
@@ -1596,13 +1636,14 @@ def _sweep_chunk_impl(site: FBSite, scen: Scenario, state: SimState,
         # same program — the chunk loop never synchronizes with the host
         fsum, fcomp = fold
         nsum, ncomp = {}, {}
-        for k in out.acc:
-            v = out.acc[k].astype(fsum[k].dtype)
-            y = v - fcomp[k]
-            t = fsum[k] + y
-            nsum[k] = t
-            ncomp[k] = (t - fsum[k]) - y
-        out = out._replace(acc=jax.tree.map(jnp.zeros_like, out.acc))
+        with jax.named_scope("fold"):
+            for k in out.acc:
+                v = out.acc[k].astype(fsum[k].dtype)
+                y = v - fcomp[k]
+                t = fsum[k] + y
+                nsum[k] = t
+                ncomp[k] = (t - fsum[k]) - y
+            out = out._replace(acc=jax.tree.map(jnp.zeros_like, out.acc))
         new_fold = (nsum, ncomp)
     if not validate:
         return out, new_fold, guard
@@ -1614,41 +1655,42 @@ def _sweep_chunk_impl(site: FBSite, scen: Scenario, state: SimState,
     # guard carries, per scenario, the first chunk index at which any
     # check failed (-1 = clean); chunk_idx/tol are traced scalars so
     # the chunk loop still reuses one executable.
-    B = guard.shape[0]
+    with jax.named_scope("guard"):
+        B = guard.shape[0]
 
-    def finite(arrs):
-        ok = jnp.ones((B,), bool)
-        for a in arrs:
-            ok &= jnp.all(jnp.isfinite(a.reshape(B, -1)), axis=1)
-        return ok
+        def finite(arrs):
+            ok = jnp.ones((B,), bool)
+            for a in arrs:
+                ok &= jnp.all(jnp.isfinite(a.reshape(B, -1)), axis=1)
+            return ok
 
-    queues = (out.rsw_q, out.csw_up_q, out.csw_down_q, out.fc_down_q)
-    ok = finite(queues)
-    if new_fold is not None:
-        tot = {k: new_fold[0][k] - new_fold[1][k] for k in new_fold[0]}
-        ok &= finite(tuple(tot.values()))
-        in_flight = sum(jnp.sum(q.reshape(B, -1), axis=1) for q in queues)
-        inj = tot["injected"]
-        resid = inj - (tot["csw_down_served"] + tot["drops"]
-                       + tot["fault_drops"] + in_flight.astype(inj.dtype))
-        ok &= jnp.abs(resid) <= tol * jnp.maximum(inj, 1.0)
-        # flow-conservation identity (exactly 0 residual at
-        # flow_mode=0, where every term is 0): started == completed +
-        # evicted + in-table; in-table counts the live usable slots of
-        # the end-of-chunk flow table
-        in_table = jnp.sum(
-            (out.ft_rem > 0.0)
-            & (jnp.arange(C.FLOW_TABLE_SLOTS)[None, None, :]
-               < scen.flow_cap[:, None, None]), axis=(1, 2))
-        started = tot["flows_started"]
-        fresid = started - (tot["flows_completed"] + tot["flows_evicted"]
-                            + in_table.astype(started.dtype))
-        ok &= jnp.abs(fresid) <= tol * jnp.maximum(started, 1.0)
-    else:
-        # host-fold path: the running totals are host-side; guard the
-        # chunk's own accumulators for finiteness only
-        ok &= finite(tuple(out.acc.values()))
-    guard = jnp.where((guard < 0) & ~ok, chunk_idx, guard)
+        queues = (out.rsw_q, out.csw_up_q, out.csw_down_q, out.fc_down_q)
+        ok = finite(queues)
+        if new_fold is not None:
+            tot = {k: new_fold[0][k] - new_fold[1][k] for k in new_fold[0]}
+            ok &= finite(tuple(tot.values()))
+            in_flight = sum(jnp.sum(q.reshape(B, -1), axis=1) for q in queues)
+            inj = tot["injected"]
+            resid = inj - (tot["csw_down_served"] + tot["drops"]
+                           + tot["fault_drops"] + in_flight.astype(inj.dtype))
+            ok &= jnp.abs(resid) <= tol * jnp.maximum(inj, 1.0)
+            # flow-conservation identity (exactly 0 residual at
+            # flow_mode=0, where every term is 0): started == completed +
+            # evicted + in-table; in-table counts the live usable slots of
+            # the end-of-chunk flow table
+            in_table = jnp.sum(
+                (out.ft_rem > 0.0)
+                & (jnp.arange(C.FLOW_TABLE_SLOTS)[None, None, :]
+                   < scen.flow_cap[:, None, None]), axis=(1, 2))
+            started = tot["flows_started"]
+            fresid = started - (tot["flows_completed"] + tot["flows_evicted"]
+                                + in_table.astype(started.dtype))
+            ok &= jnp.abs(fresid) <= tol * jnp.maximum(started, 1.0)
+        else:
+            # host-fold path: the running totals are host-side; guard the
+            # chunk's own accumulators for finiteness only
+            ok &= finite(tuple(out.acc.values()))
+        guard = jnp.where((guard < 0) & ~ok, chunk_idx, guard)
     return out, new_fold, guard
 
 
@@ -1819,37 +1861,41 @@ def _dispatch_chunks(batch: ScenarioBatch, scen: Scenario, state: SimState,
     done = start_chunk * chunk
     ci = start_chunk
     pending_write = None
-    while done < n_ticks:
-        if CHUNK_HOOK is not None:
-            CHUNK_HOOK(ci)
-        live = jnp.arange(chunk) < (n_ticks - done)
-        state, dev_fold, guard = runner(
-            hull, scen, state, chunk, live, dev_fold, guard,
-            jnp.asarray(ci, jnp.int32), tol, validate)
-        ci += 1
-        if fold == "host":
-            # legacy path: fold this chunk's accumulators into float64
-            # on the host and zero them on device — one blocking
-            # transfer per chunk
-            chunk_acc = jax.device_get(state.acc)
-            HOST_TRANSFER_COUNT += 1
-            if acc64 is None:
-                acc64 = {k: np.zeros(np.shape(v), np.float64)
-                         for k, v in chunk_acc.items()}
-            for k, v in chunk_acc.items():
-                acc64[k] += np.asarray(v, np.float64)
-            state = state._replace(
-                acc=jax.tree.map(jnp.zeros_like, state.acc))
-        done += chunk
-        if pending_write is not None:
-            pending_write()
-            pending_write = None
-        if (checkpoint is not None and done < n_ticks
-                and ci % checkpoint.every_chunks == 0):
-            pending_write = _snapshot_sweep(
-                checkpoint, batch, ci, state, dev_fold, guard,
-                n_ticks=n_ticks, chunk=chunk, validate=validate, tol=tol,
-                n_real=n_real, plan_meta=plan_meta)
+    # the bucket is known here only when a checkpoint records the plan
+    bucket = {} if plan_meta is None else {"bucket": plan_meta["bucket"]}
+    with TraceAnnotation("lcdc.dispatch", **bucket):
+        while done < n_ticks:
+            if CHUNK_HOOK is not None:
+                CHUNK_HOOK(ci)
+            with TraceAnnotation("lcdc.chunk", chunk=ci):
+                live = jnp.arange(chunk) < (n_ticks - done)
+                state, dev_fold, guard = runner(
+                    hull, scen, state, chunk, live, dev_fold, guard,
+                    jnp.asarray(ci, jnp.int32), tol, validate)
+            ci += 1
+            if fold == "host":
+                # legacy path: fold this chunk's accumulators into
+                # float64 on the host and zero them on device — one
+                # blocking transfer per chunk
+                chunk_acc = jax.device_get(state.acc)
+                HOST_TRANSFER_COUNT += 1
+                if acc64 is None:
+                    acc64 = {k: np.zeros(np.shape(v), np.float64)
+                             for k, v in chunk_acc.items()}
+                for k, v in chunk_acc.items():
+                    acc64[k] += np.asarray(v, np.float64)
+                state = state._replace(
+                    acc=jax.tree.map(jnp.zeros_like, state.acc))
+            done += chunk
+            if pending_write is not None:
+                pending_write()
+                pending_write = None
+            if (checkpoint is not None and done < n_ticks
+                    and ci % checkpoint.every_chunks == 0):
+                pending_write = _snapshot_sweep(
+                    checkpoint, batch, ci, state, dev_fold, guard,
+                    n_ticks=n_ticks, chunk=chunk, validate=validate,
+                    tol=tol, n_real=n_real, plan_meta=plan_meta)
     return _PendingSweep(batch=batch, n_ticks=n_ticks, fold=dev_fold,
                          acc64=acc64, state=state, n_real=n_real,
                          guard=guard)
@@ -1884,9 +1930,10 @@ def _start_sweep(batch: ScenarioBatch, n_ticks: int, *,
             "checkpointing requires the device-resident fold "
             f"(fold='device'); got fold={fold!r}")
     n_real = len(batch)
-    scen, state, dev_fold, guard, tol = _prepare_sweep_args(
-        batch, fold=fold, shard=shard, validate=validate,
-        validate_tol=validate_tol)
+    with TraceAnnotation("lcdc.init", rows=n_real):
+        scen, state, dev_fold, guard, tol = _prepare_sweep_args(
+            batch, fold=fold, shard=shard, validate=validate,
+            validate_tol=validate_tol)
     return _dispatch_chunks(
         batch, scen, state, dev_fold, guard, tol, n_ticks=n_ticks,
         chunk=max(1, min(chunk_ticks, n_ticks)), fold=fold,
@@ -1903,10 +1950,11 @@ def _finish_sweep(p: _PendingSweep, return_state: bool = False):
     global HOST_TRANSFER_COUNT
     guard_h = None
     if p.fold is not None:
-        if p.guard is not None:
-            (fsum, fcomp), guard_h = jax.device_get((p.fold, p.guard))
-        else:
-            fsum, fcomp = jax.device_get(p.fold)
+        with TraceAnnotation("lcdc.fetch"):
+            if p.guard is not None:
+                (fsum, fcomp), guard_h = jax.device_get((p.fold, p.guard))
+            else:
+                fsum, fcomp = jax.device_get(p.fold)
         HOST_TRANSFER_COUNT += 1
         # Kahan: sum carries the running total, comp the rounding error
         # still to subtract; apply the residual in float64 on the host
@@ -1915,7 +1963,8 @@ def _finish_sweep(p: _PendingSweep, return_state: bool = False):
     else:
         acc64 = p.acc64
         if p.guard is not None:
-            guard_h = jax.device_get(p.guard)
+            with TraceAnnotation("lcdc.fetch"):
+                guard_h = jax.device_get(p.guard)
             HOST_TRANSFER_COUNT += 1
     batch = p.batch
     if guard_h is not None:
@@ -1924,12 +1973,13 @@ def _finish_sweep(p: _PendingSweep, return_state: bool = False):
             raise SweepValidationError(
                 [batch.labels[i] for i in bad],
                 min(int(guard_h[i]) for i in bad))
-    res = [
-        _finalize({k: v[i] for k, v in acc64.items()}, batch.sites[i],
-                  p.n_ticks, batch.gating[i], batch.names[i],
-                  batch.labels[i])
-        for i in range(len(batch))
-    ]
+    with TraceAnnotation("lcdc.finalize", rows=len(batch)):
+        res = [
+            _finalize({k: v[i] for k, v in acc64.items()}, batch.sites[i],
+                      p.n_ticks, batch.gating[i], batch.names[i],
+                      batch.labels[i])
+            for i in range(len(batch))
+        ]
     if return_state:
         state = jax.device_get(p.state)
         # drop devices-multiple pad rows (copies of scenario 0)
@@ -1958,8 +2008,9 @@ def _snapshot_sweep(spec: CheckpointSpec, batch: ScenarioBatch,
     and scenarios are independent vmap lanes.
     """
     global HOST_TRANSFER_COUNT
-    scen_h, state_h, fold_h, guard_h = jax.device_get(
-        (batch.scen, state, dev_fold, guard))
+    with TraceAnnotation("lcdc.snapshot", chunk=ci):
+        scen_h, state_h, fold_h, guard_h = jax.device_get(
+            (batch.scen, state, dev_fold, guard))
     HOST_TRANSFER_COUNT += 1
     state_h = jax.tree.map(lambda x: np.asarray(x)[:n_real], state_h)
     arrays = {}
@@ -1994,8 +2045,9 @@ def _snapshot_sweep(spec: CheckpointSpec, batch: ScenarioBatch,
     }
 
     def write():
-        path = _ckpt.write_checkpoint(spec.path_for(ci), meta, arrays)
-        _ckpt.prune(spec)
+        with TraceAnnotation("lcdc.snapshot", chunk=ci):
+            path = _ckpt.write_checkpoint(spec.path_for(ci), meta, arrays)
+            _ckpt.prune(spec)
         return path
     return write
 
@@ -2271,165 +2323,172 @@ def run_sweep_planned(runs: Sequence[tuple[SimParams, int]], n_ticks: int,
             "checkpointing requires the device-resident fold "
             f"(fold='device'); got fold={fold!r}")
     runs = list(runs)
-    plan = planner.plan_sites([p.site for p, _ in runs], max_compiles)
-    order = plan.dispatch_order if pipeline \
-        else tuple(range(len(plan.buckets)))
-    policy = retry if retry is not None else BucketRetryPolicy()
-    pending: dict[int, _PendingSweep] = {}
-    fetched: dict[int, list] = {}
-    errors: dict[int, dict] = {}
-    elapsed: dict[int, float] = {}
+    with TraceAnnotation("lcdc.sweep", rows=len(runs), ticks=n_ticks):
+        with TraceAnnotation("lcdc.plan"):
+            plan = planner.plan_sites([p.site for p, _ in runs],
+                                      max_compiles)
+        order = plan.dispatch_order if pipeline \
+            else tuple(range(len(plan.buckets)))
+        policy = retry if retry is not None else BucketRetryPolicy()
+        pending: dict[int, _PendingSweep] = {}
+        fetched: dict[int, list] = {}
+        errors: dict[int, dict] = {}
+        elapsed: dict[int, float] = {}
 
-    def hook(k, phase):
-        if BUCKET_FAIL_HOOK is not None:
-            BUCKET_FAIL_HOOK(k, phase)
+        def hook(k, phase):
+            if BUCKET_FAIL_HOOK is not None:
+                BUCKET_FAIL_HOOK(k, phase)
 
-    def timed(k, fn):
-        # per-bucket wall-clock ledger: cumulative across the bucket's
-        # dispatch, fetch and retry attempts; the policy's deadline_s
-        # is checked against it before each retry
-        t0 = time.monotonic()
-        try:
-            return fn()
-        finally:
-            elapsed[k] = elapsed.get(k, 0.0) + (time.monotonic() - t0)
+        def timed(k, fn):
+            # per-bucket wall-clock ledger: cumulative across the bucket's
+            # dispatch, fetch and retry attempts; the policy's deadline_s
+            # is checked against it before each retry
+            t0 = time.monotonic()
+            try:
+                return fn()
+            finally:
+                elapsed[k] = elapsed.get(k, 0.0) + (time.monotonic() - t0)
 
-    def bucket_spec(k):
-        if checkpoint is None:
-            return None
-        return dataclasses.replace(
-            checkpoint, tag=f"{checkpoint.tag}-{plan.bucket_tag(k)}")
+        def bucket_spec(k):
+            if checkpoint is None:
+                return None
+            return dataclasses.replace(
+                checkpoint, tag=f"{checkpoint.tag}-{plan.bucket_tag(k)}")
 
-    def bucket_plan_meta(k):
-        return {"fingerprint": plan.fingerprint, "bucket": k,
-                "hull": full_site_tag(plan.buckets[k].hull)}
+        def bucket_plan_meta(k):
+            return {"fingerprint": plan.fingerprint, "bucket": k,
+                    "hull": full_site_tag(plan.buckets[k].hull)}
 
-    def salvage_checkpoint(k, spec_k):
-        # a resumable artifact for the exhausted bucket: its newest
-        # cadence snapshot if it reached a boundary, else a fresh
-        # chunk-0 snapshot of its INITIAL carry (resuming that replays
-        # the whole bucket). Best-effort: None if even this fails.
-        existing = _ckpt.latest_checkpoint(spec_k.directory, spec_k.tag)
-        if existing is not None:
-            return str(existing)
-        try:
-            batch = make_multi_site_batch(
-                [runs[i] for i in plan.buckets[k].indices])
-            scen, state, dev_fold, guard, tol = _prepare_sweep_args(
-                batch, fold="device", shard=shard, validate=validate,
-                validate_tol=validate_tol)
-            return str(_snapshot_sweep(
-                spec_k, batch, 0, state, dev_fold, guard,
-                n_ticks=n_ticks,
-                chunk=max(1, min(chunk_ticks, n_ticks)),
-                validate=validate, tol=tol, n_real=len(batch),
-                plan_meta=bucket_plan_meta(k))())
-        except Exception:                  # noqa: BLE001 — best effort
-            return None
-
-    def retry_bucket(k, stage, exc):
-        # bounded retries on the most conservative path; on exhaustion
-        # record a structured error for the bucket (stage = the
-        # ORIGINAL failure's phase, message = the final failure's)
-        last = exc
-        retried = False
-        for attempt in range(1, policy.max_retries + 1):
-            if (policy.deadline_s is not None
-                    and elapsed.get(k, 0.0) >= policy.deadline_s):
-                break
-            delay = policy.backoff_s(attempt)
-            if delay > 0.0:
-                RETRY_SLEEP(delay)
-            retried = True
-
-            def one_retry():
-                hook(k, "retry")
+        def salvage_checkpoint(k, spec_k):
+            # a resumable artifact for the exhausted bucket: its newest
+            # cadence snapshot if it reached a boundary, else a fresh
+            # chunk-0 snapshot of its INITIAL carry (resuming that replays
+            # the whole bucket). Best-effort: None if even this fails.
+            existing = _ckpt.latest_checkpoint(spec_k.directory, spec_k.tag)
+            if existing is not None:
+                return str(existing)
+            try:
                 batch = make_multi_site_batch(
                     [runs[i] for i in plan.buckets[k].indices])
-                return _finish_sweep(_start_sweep(
-                    batch, n_ticks, chunk_ticks=chunk_ticks,
-                    fold="host", shard=shard, validate=validate,
-                    validate_tol=validate_tol))
+                scen, state, dev_fold, guard, tol = _prepare_sweep_args(
+                    batch, fold="device", shard=shard, validate=validate,
+                    validate_tol=validate_tol)
+                return str(_snapshot_sweep(
+                    spec_k, batch, 0, state, dev_fold, guard,
+                    n_ticks=n_ticks,
+                    chunk=max(1, min(chunk_ticks, n_ticks)),
+                    validate=validate, tol=tol, n_real=len(batch),
+                    plan_meta=bucket_plan_meta(k))())
+            except Exception:                  # noqa: BLE001 — best effort
+                return None
 
-            try:
-                fetched[k] = timed(k, one_retry)
-                return
-            except Exception as exc2:      # noqa: BLE001 — isolation
-                last = exc2
-        errors[k] = {"type": type(last).__name__, "message": str(last),
-                     "stage": stage, "retried": retried}
-        spec_k = bucket_spec(k)
-        if spec_k is not None:
-            errors[k]["checkpoint"] = salvage_checkpoint(k, spec_k)
+        def retry_bucket(k, stage, exc):
+            # bounded retries on the most conservative path; on exhaustion
+            # record a structured error for the bucket (stage = the
+            # ORIGINAL failure's phase, message = the final failure's)
+            last = exc
+            retried = False
+            for attempt in range(1, policy.max_retries + 1):
+                if (policy.deadline_s is not None
+                        and elapsed.get(k, 0.0) >= policy.deadline_s):
+                    break
+                delay = policy.backoff_s(attempt)
+                if delay > 0.0:
+                    RETRY_SLEEP(delay)
+                retried = True
 
-    try:
-        for k in order:
-            bucket = plan.buckets[k]
+                def one_retry():
+                    with TraceAnnotation("lcdc.retry", bucket=k,
+                                         attempt=attempt):
+                        hook(k, "retry")
+                        with TraceAnnotation("lcdc.batch", bucket=k):
+                            batch = make_multi_site_batch(
+                                [runs[i] for i in plan.buckets[k].indices])
+                        return _finish_sweep(_start_sweep(
+                            batch, n_ticks, chunk_ticks=chunk_ticks,
+                            fold="host", shard=shard, validate=validate,
+                            validate_tol=validate_tol))
 
-            def dispatch(k=k, bucket=bucket):
-                hook(k, "dispatch")
-                batch = make_multi_site_batch(
-                    [runs[i] for i in bucket.indices])
-                return _start_sweep(
-                    batch, n_ticks, chunk_ticks=chunk_ticks, fold=fold,
-                    shard=shard, validate=validate,
-                    validate_tol=validate_tol,
-                    checkpoint=bucket_spec(k),
-                    plan_meta=bucket_plan_meta(k)
-                    if checkpoint is not None else None)
-
-            try:
-                ps = timed(k, dispatch)
-            except Exception as exc:       # noqa: BLE001 — isolation
-                retry_bucket(k, "dispatch", exc)
-                continue
-            if pipeline:
-                pending[k] = ps
-            else:
-                # strictly serial: block on this bucket before the
-                # next, and drop ps so its device state/fold buffers
-                # free now — this IS the advertised one-bucket-resident
-                # memory mode
                 try:
-                    def fetch(ps=ps, k=k):
+                    fetched[k] = timed(k, one_retry)
+                    return
+                except Exception as exc2:      # noqa: BLE001 — isolation
+                    last = exc2
+            errors[k] = {"type": type(last).__name__, "message": str(last),
+                         "stage": stage, "retried": retried}
+            spec_k = bucket_spec(k)
+            if spec_k is not None:
+                errors[k]["checkpoint"] = salvage_checkpoint(k, spec_k)
+
+        try:
+            for k in order:
+                bucket = plan.buckets[k]
+
+                def dispatch(k=k, bucket=bucket):
+                    hook(k, "dispatch")
+                    with TraceAnnotation("lcdc.batch", bucket=k):
+                        batch = make_multi_site_batch(
+                            [runs[i] for i in bucket.indices])
+                    return _start_sweep(
+                        batch, n_ticks, chunk_ticks=chunk_ticks, fold=fold,
+                        shard=shard, validate=validate,
+                        validate_tol=validate_tol,
+                        checkpoint=bucket_spec(k),
+                        plan_meta=bucket_plan_meta(k)
+                        if checkpoint is not None else None)
+
+                try:
+                    ps = timed(k, dispatch)
+                except Exception as exc:       # noqa: BLE001 — isolation
+                    retry_bucket(k, "dispatch", exc)
+                    continue
+                if pipeline:
+                    pending[k] = ps
+                else:
+                    # strictly serial: block on this bucket before the
+                    # next, and drop ps so its device state/fold buffers
+                    # free now — this IS the advertised one-bucket-resident
+                    # memory mode
+                    try:
+                        def fetch(ps=ps, k=k):
+                            hook(k, "fetch")
+                            return _finish_sweep(ps)
+                        fetched[k] = timed(k, fetch)
+                    except Exception as exc:   # noqa: BLE001 — isolation
+                        retry_bucket(k, "fetch", exc)
+            for k in (k for k in order if k in pending):
+                try:
+                    def fetch(k=k):
                         hook(k, "fetch")
-                        return _finish_sweep(ps)
+                        return _finish_sweep(pending.pop(k))
                     fetched[k] = timed(k, fetch)
-                except Exception as exc:   # noqa: BLE001 — isolation
+                except Exception as exc:       # noqa: BLE001 — isolation
                     retry_bucket(k, "fetch", exc)
-        for k in (k for k in order if k in pending):
-            try:
-                def fetch(k=k):
-                    hook(k, "fetch")
-                    return _finish_sweep(pending.pop(k))
-                fetched[k] = timed(k, fetch)
-            except Exception as exc:       # noqa: BLE001 — isolation
-                retry_bucket(k, "fetch", exc)
-    finally:
-        # a raising fetch (pre-isolation this propagated) must never
-        # leave later buckets' device state/fold buffers referenced
-        pending.clear()
-    results: list = [None] * len(runs)
-    for k, bucket in enumerate(plan.buckets):
-        # the FULL tag — the same format the plan report's bucket
-        # "hull" field uses, so the two can be joined on it
-        hull_tag = full_site_tag(bucket.hull)
-        if k in fetched:
-            for i, r in zip(bucket.indices, fetched[k]):
-                r["plan_bucket"] = k
-                r["plan_hull"] = hull_tag
-                results[i] = r
-        else:
-            for i in bucket.indices:
-                p, seed = runs[i]
-                results[i] = {
-                    "label": _run_label(p, seed, tag_site=True),
-                    "plan_bucket": k, "plan_hull": hull_tag,
-                    "error": dict(errors[k]),
-                }
-    if return_plan:
-        return results, plan.report()
-    return results
+        finally:
+            # a raising fetch (pre-isolation this propagated) must never
+            # leave later buckets' device state/fold buffers referenced
+            pending.clear()
+        results: list = [None] * len(runs)
+        for k, bucket in enumerate(plan.buckets):
+            # the FULL tag — the same format the plan report's bucket
+            # "hull" field uses, so the two can be joined on it
+            hull_tag = full_site_tag(bucket.hull)
+            if k in fetched:
+                for i, r in zip(bucket.indices, fetched[k]):
+                    r["plan_bucket"] = k
+                    r["plan_hull"] = hull_tag
+                    results[i] = r
+            else:
+                for i in bucket.indices:
+                    p, seed = runs[i]
+                    results[i] = {
+                        "label": _run_label(p, seed, tag_site=True),
+                        "plan_bucket": k, "plan_hull": hull_tag,
+                        "error": dict(errors[k]),
+                    }
+        if return_plan:
+            return results, plan.report()
+        return results
 
 
 def _hist_quantile(hist: np.ndarray, q: float,

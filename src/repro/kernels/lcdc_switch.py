@@ -184,6 +184,7 @@ def switch_step(queues, stage, arrivals, draining=None, *, valid=None,
         out_specs=[spec_l] * (2 * K) + [spec_1] * 6,
         out_shape=[plane] * (2 * K) + [flag, flag] + [column] * 4,
         interpret=interpret,
+        name="lcdc_switch_step",
     )(*[qp[..., k] for k in range(K)], stage_p, drain_p, valid_p,
       *[arr_p[:, k:k + 1] for k in range(K)], col(cap), col(hi), col(lo))
     qo = jnp.stack(outs[:K], axis=-1)[:S]

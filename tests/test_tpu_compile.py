@@ -5,11 +5,15 @@ and vmapped over a scenario batch) and the whole FBSite() chunk program
 with the kernel in it, on one chip and sharded over a 2x2 mesh. Nothing
 runs: this catches what Mosaic or XLA:TPU refuses (unsupported
 primitives and layouts, a kernel that cannot be partitioned, a program
-that does not fit) before any chip time is spent.
+that does not fit) before any chip time is spent. The chunk program
+must also keep the names a profile is read by: the kernel's
+``lcdc_switch_step`` under each tier's scope, and every part scope.
 
 The topology is described inside a fixture: loading the TPU compiler
 at import time would break collection under several pytest workers.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -63,6 +67,23 @@ def _spec(x, sharding, rows=None):
 def _footprint(mem) -> int:
     return (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+
+
+#: the step's part scopes and the fold's and guard's, which a profile
+#: reads from the ops' op_name (chipbench/progtrace.py)
+SCOPES = ("edge", "flows", "rsw", "csw", "down", "stats", "gating", "fold",
+          "guard", "draws")
+
+
+def _assert_names(txt):
+    """The switch kernel runs under its own name once in each tier's
+    scope, and every part scope is in the program's op_names."""
+    calls = re.findall(r'custom_call_target="tpu_custom_call".*?'
+                       r'op_name="[^"]*/vmap\((\w+)\)/lcdc_switch_step/'
+                       r'pallas_call"', txt)
+    assert sorted(calls) == ["csw", "rsw"]
+    for scope in SCOPES:
+        assert re.search(rf'op_name="[^"]*[/(]{scope}[)/]', txt), scope
 
 
 # (name, switches, links, components, serve rate): the Fig 2 site's
@@ -126,6 +147,7 @@ def test_chunk_program_compiles_for_one_v5e(one_chip, tpu_compile):
         _spec(tol, one_chip), True).compile()
     txt = compiled.as_text()
     assert txt.count("tpu_custom_call") == 2     # the RSW and CSW tiers
+    _assert_names(txt)
     mem = compiled.memory_analysis()
     carry = sum(int(np.prod(x.shape)) * x.dtype.itemsize
                 for x in jax.tree.leaves((state, fold)))
@@ -159,5 +181,6 @@ def test_sharded_chunk_program_compiles_for_v5e_2x2(topo, tpu_compile):
         True).compile()
     txt = compiled.as_text()
     assert txt.count("tpu_custom_call") == 2
+    _assert_names(txt)
     assert hlo.parse_collectives(txt).by_op() == {}
     assert _footprint(compiled.memory_analysis()) < V5E_HBM_BYTES // 16
