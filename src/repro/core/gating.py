@@ -303,8 +303,10 @@ def gate_step(state: GateState, queues: jnp.ndarray,
 
     # drained? (top queue empty) -> drop the stage NOW (link unusable) and
     # begin the 10us power-off transition (still charged: off_timer)
-    top_q = jnp.take_along_axis(queues, (stage - 1)[:, None],
-                                axis=1)[:, 0]
+    # a one-hot select over the link axis, not a gather (a TPU pays per
+    # gathered element); exactly one term is non-zero
+    top_q = jnp.sum(jnp.where(idx == (stage - 1)[:, None], queues, 0.0),
+                    axis=1)
     begin_off = draining & (top_q <= 0) & (stage > 1)
     stage = jnp.where(begin_off, stage - 1, stage)
     off_timer = jnp.where(begin_off, off_delay, off_timer)

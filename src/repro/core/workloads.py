@@ -89,19 +89,28 @@ def sample_flow_size_pkts(u, dist):
     frac = (u - p0)/(p1 - p0) — monotone in u within and across
     segments, so the sampler itself is monotone (the hypothesis
     property tests/test_flows.py pins). Pure f32, no host branching.
+    No gathers, for the TPU: each table lookup is a one-hot select
+    summed over the small static axis it indexes (one non-zero term,
+    every table value finite and >= +0.0, so the sum is exact).
     """
-    size_tab = jnp.asarray(CDF_SIZE_PKTS)[dist]          # (P,)
-    prob_tab = jnp.asarray(CDF_PROB)[dist]
+    ndist, npts = CDF_PROB.shape
+    row = (jnp.asarray(dist) == jnp.arange(ndist))[:, None]     # (D, 1)
+    size_tab = jnp.sum(jnp.where(row, CDF_SIZE_PKTS, 0.0), axis=0)  # (P,)
+    prob_tab = jnp.sum(jnp.where(row, CDF_PROB, 0.0), axis=0)
     u = jnp.asarray(u, jnp.float32)
-    npts = CDF_PROB.shape[1]
     # segment index: the last anchor with prob <= u (atoms — repeated
     # sizes — collapse to a zero-length segment whose interp is exact)
     seg = jnp.clip(jnp.sum((u[..., None] >= prob_tab).astype(jnp.int32),
                            axis=-1) - 1, 0, npts - 2)
-    lo_s = jnp.take(size_tab, seg)
-    hi_s = jnp.take(size_tab, seg + 1)
-    lo_p = jnp.take(prob_tab, seg)
-    hi_p = jnp.take(prob_tab, seg + 1)
+    anchor = jnp.arange(npts)
+    at_lo = seg[..., None] == anchor
+    at_hi = (seg + 1)[..., None] == anchor
+
+    def pick(mask, tab):
+        return jnp.sum(jnp.where(mask, tab, 0.0), axis=-1)
+
+    lo_s, hi_s = pick(at_lo, size_tab), pick(at_hi, size_tab)
+    lo_p, hi_p = pick(at_lo, prob_tab), pick(at_hi, prob_tab)
     frac = jnp.clip((u - lo_p) / jnp.maximum(hi_p - lo_p, 1e-9),
                     0.0, 1.0)
     size = lo_s * (hi_s / lo_s) ** frac

@@ -1,8 +1,9 @@
 """Flow-level workload engine (flow_mode=1): bit-parity of flow_mode=0
 against the committed pre-flow golden results, flow-knob inertness, the
 exact flow-conservation census under gating + faults, table-overflow
-eviction accounting, the sampler monotonicity property, and the
-one-trace / one-transfer pins on a flow batch."""
+eviction accounting, the sampler monotonicity property and its
+bit-for-bit match with a NumPy inverse CDF, and the one-trace /
+one-transfer pins on a flow batch."""
 import json
 from pathlib import Path
 
@@ -203,6 +204,49 @@ def test_flow_size_sampler_monotone_integral(dist, u1, u2):
     assert (s >= 1.0).all()
     assert (s == np.floor(s)).all()
     assert s[1] <= workloads.CDF_SIZE_PKTS[dist].max()
+
+
+def _inverse_cdf_reference(u, dist):
+    """The sampler's arithmetic in plain NumPy f32 over the same anchor
+    tables: the segment by ``np.searchsorted``, the anchors by indexing.
+    The power alone is ``jnp.power``: NumPy's float32 power and XLA's
+    differ in the last bit, and ceil carries that into the size."""
+    size, prob = workloads.CDF_SIZE_PKTS[dist], workloads.CDF_PROB[dist]
+    seg = np.clip(np.searchsorted(prob, u, side="right") - 1,
+                  0, len(prob) - 2)
+    lo_s, hi_s = size[seg], size[seg + 1]
+    lo_p, hi_p = prob[seg], prob[seg + 1]
+    frac = np.clip((u - lo_p) / np.maximum(hi_p - lo_p, np.float32(1e-9)),
+                   np.float32(0.0), np.float32(1.0))
+    grow = np.asarray(jnp.power(hi_s / lo_s, frac))
+    out = np.maximum(np.ceil(lo_s * grow), np.float32(1.0))
+    assert out.dtype == np.float32
+    return out
+
+
+@pytest.mark.parametrize("jit", (False, True), ids=("eager", "jit"))
+def test_flow_size_sampler_is_the_inverse_cdf_bit_for_bit(jit):
+    """Every anchor probability, each anchor +- 1 ulp and 10^5 seeded
+    uniforms per distribution, vmapped over a ``dist`` vector as the
+    simulator runs it: the same bits as the NumPy inverse CDF."""
+    rng = np.random.default_rng(20211202)
+    ndist = len(workloads.FLOW_DIST_NAMES)
+    rows = []
+    for d in range(ndist):
+        p = workloads.CDF_PROB[d]
+        rows.append(np.concatenate([
+            p, np.nextafter(p, np.float32(-1.0)),
+            np.nextafter(p, np.float32(2.0)),
+            rng.random(100_000, dtype=np.float32)]))
+    u = np.stack(rows)
+    sample = jax.vmap(workloads.sample_flow_size_pkts)
+    if jit:
+        sample = jax.jit(sample)
+    got = np.asarray(sample(jnp.asarray(u), jnp.arange(ndist)))
+    for d in range(ndist):
+        want = _inverse_cdf_reference(u[d], d)
+        np.testing.assert_array_equal(got[d].view(np.uint32),
+                                      want.view(np.uint32))
 
 
 def test_flow_size_classes():

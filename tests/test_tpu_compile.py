@@ -128,8 +128,8 @@ def _chunk_operands():
 
 def test_chunk_program_compiles_for_one_v5e(one_chip, tpu_compile):
     """The FBSite() chunk program as the TPU backend builds it (carry
-    donated), both switch kernels inside, the carry aliased in place,
-    and well inside one chip's HBM."""
+    donated), both switch kernels inside, no gather, the carry aliased
+    in place, and well inside one chip's HBM."""
     batch, scen, state, fold, guard, tol = _chunk_operands()
     chunk = S.CHUNK_TICKS
 
@@ -147,6 +147,7 @@ def test_chunk_program_compiles_for_one_v5e(one_chip, tpu_compile):
         _spec(tol, one_chip), True).compile()
     txt = compiled.as_text()
     assert txt.count("tpu_custom_call") == 2     # the RSW and CSW tiers
+    assert "gather(" not in txt                  # table lookups are selects
     _assert_names(txt)
     mem = compiled.memory_analysis()
     carry = sum(int(np.prod(x.shape)) * x.dtype.itemsize

@@ -118,12 +118,21 @@ def test_every_part_scope_is_in_the_chunk_program(chunk_hlo):
 def test_draws_hold_the_sampler_and_the_random_ops(chunk_hlo):
     sampler = [r for r in chunk_hlo.rows
                if r["fn"] == "sample_flow_size_pkts"]
-    assert any(r["op"] == "gather" for r in sampler)
+    assert sampler
     random = [r for r in chunk_hlo.rows if re.search(
         r"threefry2x32|random_bits|random_fold_in|random_split"
         r"|random_wrap|jit\(_uniform\)|jit\(_normal\)", r["op_name"])]
     assert random
     assert {r["part"] for r in sampler + random} == {progtrace.DRAWS}
+
+
+def test_scan_body_holds_no_gather(chunk_hlo):
+    # the step's table lookups (the flow-size sampler's anchors and
+    # distribution row, gating's top queue) are one-hot selects: a
+    # per-element gather costs the TPU far more than the select
+    body = [r for r in chunk_hlo.rows if "/while/body/" in r["op_name"]]
+    assert body
+    assert [r["op_name"] for r in body if r["op"] == "gather"] == []
 
 
 def test_switch_step_and_fold_sit_in_their_parts(chunk_hlo):
