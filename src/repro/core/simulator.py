@@ -891,7 +891,9 @@ def make_sim_step(hull: FBSite):
     Each part of the step runs under a ``jax.named_scope``: ``edge``,
     ``flows``, ``rsw``, ``csw``, ``down``, ``stats`` and ``gating``,
     with a nested ``draws`` around the random draws and the transforms
-    of uniforms into samples. The scopes change only the ops' metadata
+    of uniforms into samples, and a nested ``faults`` in ``gating``
+    around the hard-fault arrivals, the fault-drop moves and the
+    connectivity audit. The scopes change only the ops' metadata
     (``op_name``); chipbench/progtrace.py reads them from a profile to
     split the step's device time by part."""
     s = hull
@@ -1371,23 +1373,24 @@ def make_sim_step(hull: FBSite):
             # the fault-drop conservation bin (a dead laser transmits
             # nothing; injected == delivered + in-flight + drops +
             # fault_drops stays exact).
-            rsw_timer, rsw_new_f = gating.fault_arrivals(
-                state.rsw_fault.timer, u_fr[:, 2:2 + P],
-                state.rsw_gate.powered, rsw_link_real,
-                scen.fault_prob, scen.repair_ticks,
-                plane_u=u_plane_r, plane_fail_prob=scen.plane_fail_prob)
-            csw_timer, csw_new_f = gating.fault_arrivals(
-                state.csw_fault.timer, u_fc[:, 2:2 + CUP],
-                state.csw_gate.powered, csw_link_real,
-                scen.fault_prob, scen.repair_ticks,
-                plane_u=u_plane_c, plane_fail_prob=scen.plane_fail_prob)
-            acc["fault_drops"] += \
-                jnp.sum(jnp.where(rsw_new_f[..., None], rsw_q, 0.0)) \
-                + jnp.sum(jnp.where(csw_new_f, csw_up_q, 0.0))
-            rsw_q = jnp.where(rsw_new_f[..., None], 0.0, rsw_q)
-            csw_up_q = jnp.where(csw_new_f, 0.0, csw_up_q)
-            acc["fault_link_ticks"] += jnp.sum(rsw_timer > 0) \
-                + jnp.sum(csw_timer > 0)
+            with jax.named_scope("faults"):
+                rsw_timer, rsw_new_f = gating.fault_arrivals(
+                    state.rsw_fault.timer, u_fr[:, 2:2 + P],
+                    state.rsw_gate.powered, rsw_link_real,
+                    scen.fault_prob, scen.repair_ticks,
+                    plane_u=u_plane_r, plane_fail_prob=scen.plane_fail_prob)
+                csw_timer, csw_new_f = gating.fault_arrivals(
+                    state.csw_fault.timer, u_fc[:, 2:2 + CUP],
+                    state.csw_gate.powered, csw_link_real,
+                    scen.fault_prob, scen.repair_ticks,
+                    plane_u=u_plane_c, plane_fail_prob=scen.plane_fail_prob)
+                acc["fault_drops"] += \
+                    jnp.sum(jnp.where(rsw_new_f[..., None], rsw_q, 0.0)) \
+                    + jnp.sum(jnp.where(csw_new_f, csw_up_q, 0.0))
+                rsw_q = jnp.where(rsw_new_f[..., None], 0.0, rsw_q)
+                csw_up_q = jnp.where(csw_new_f, 0.0, csw_up_q)
+                acc["fault_link_ticks"] += jnp.sum(rsw_timer > 0) \
+                    + jnp.sum(csw_timer > 0)
 
             # 9b. the controllers, fault-aware: jittered/failing wakes plus
             # the min-connectivity fallback (force-wake the cheapest healthy
@@ -1441,20 +1444,21 @@ def make_sim_step(hull: FBSite):
             # policy can preserve its connectivity, and that hardware
             # unavailability is already visible in link_fault_frac /
             # delivered_frac.
-            rsw_healthy = (rsw_timer == 0) & rsw_link_real
-            csw_healthy = (csw_timer == 0) & csw_link_real
-            rsw_usable_f = gating.usable_links(rsw_gate.stage,
-                                               rsw_gate.draining, P) \
-                & rsw_healthy
-            csw_usable_f = gating.usable_links(csw_gate.stage,
-                                               csw_gate.draining, CUP) \
-                & csw_healthy
-            acc["conn_loss_rack_ticks"] += jnp.sum(
-                rack_valid & jnp.any(rsw_healthy, axis=1)
-                & ~jnp.any(rsw_usable_f, axis=1))
-            acc["conn_loss_csw_ticks"] += jnp.sum(
-                csw_valid & jnp.any(csw_healthy, axis=1)
-                & ~jnp.any(csw_usable_f, axis=1))
+            with jax.named_scope("faults"):
+                rsw_healthy = (rsw_timer == 0) & rsw_link_real
+                csw_healthy = (csw_timer == 0) & csw_link_real
+                rsw_usable_f = gating.usable_links(rsw_gate.stage,
+                                                   rsw_gate.draining, P) \
+                    & rsw_healthy
+                csw_usable_f = gating.usable_links(csw_gate.stage,
+                                                   csw_gate.draining, CUP) \
+                    & csw_healthy
+                acc["conn_loss_rack_ticks"] += jnp.sum(
+                    rack_valid & jnp.any(rsw_healthy, axis=1)
+                    & ~jnp.any(rsw_usable_f, axis=1))
+                acc["conn_loss_csw_ticks"] += jnp.sum(
+                    csw_valid & jnp.any(csw_healthy, axis=1)
+                    & ~jnp.any(csw_usable_f, axis=1))
 
             # power accounting: a hard-faulted transceiver is dark — it
             # draws nothing while dead, whatever the controller thinks

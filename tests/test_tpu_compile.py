@@ -70,9 +70,10 @@ def _footprint(mem) -> int:
 
 
 #: the step's part scopes and the fold's and guard's, which a profile
-#: reads from the ops' op_name (chipbench/progtrace.py)
+#: reads from the ops' op_name (chipbench/progtrace.py), and the nested
+#: ``faults`` (chipbench/metrics/faults_us_per_tick.py)
 SCOPES = ("edge", "flows", "rsw", "csw", "down", "stats", "gating", "fold",
-          "guard", "draws")
+          "guard", "draws", "faults")
 
 
 def _assert_names(txt):

@@ -3,7 +3,10 @@
 - The chunk program's compiled HLO carries the step's part scopes in
   its ops' ``op_name`` metadata: the random draws and the flow-size
   sampler under ``draws``, the switch step under ``rsw`` and ``csw``,
-  the Kahan fold under ``fold``, the validate guard under ``guard``.
+  the Kahan fold under ``fold``, the validate guard under ``guard``;
+  the hard-fault arrivals and the connectivity audit under ``faults``
+  inside ``gating``, which chipbench/metrics/faults_us_per_tick.py
+  reads (ops whose path holds ``faults`` and not ``draws``).
 - A profiled planned sweep records the executor's ``lcdc.*`` host spans
   in their tree, with their stats, and returns exactly what the same
   sweep returns with no profiler running.
@@ -154,6 +157,122 @@ def test_unscoped_share_of_the_scan_body_is_small(chunk_hlo):
             if "/while/body/" in r["op_name"]]
     parts = Counter(r["part"] for r in body)
     assert body and parts[progtrace.UNSCOPED] < 0.1 * len(body), parts
+
+
+def _path(op_name: str) -> list:
+    return [progtrace.unwrap(c) for c in op_name.split("/")]
+
+
+def test_fault_arrivals_sit_in_faults_inside_gating(chunk_hlo):
+    # the hard-fault arrivals of both tiers (gating.fault_arrivals) carry
+    # the nested scope and still count for gating; every op that holds
+    # the scope counts for gating, and the fault draws stay in draws
+    arrivals = [r for r in chunk_hlo.rows if r["fn"] == "fault_arrivals"]
+    assert arrivals
+    for r in arrivals:
+        assert "faults" in _path(r["op_name"]), r["op_name"]
+        assert r["part"] == "gating", r["op_name"]
+    faults = [r for r in chunk_hlo.rows if "faults" in _path(r["op_name"])]
+    assert {r["part"] for r in faults} == {"gating"}
+    # the connectivity audit's reductions (the fault code's only
+    # jnp.any) survive compilation under the scope
+    assert any(r["op_name"].endswith("/faults/reduce_or") for r in faults)
+
+
+def _eqns(jaxpr, prefix=""):
+    """(equation, its name stack from the top) of a jaxpr and of every
+    jaxpr inside it."""
+    for e in jaxpr.eqns:
+        names = f"{prefix}/{e.source_info.name_stack}"
+        yield e, names
+        for v in e.params.values():
+            for sub in v if isinstance(v, (list, tuple)) else (v,):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    yield from _eqns(inner, names)
+
+
+def test_connectivity_audit_sits_in_faults_inside_gating():
+    # the optimized HLO keeps too few stack frames to find the audit by
+    # its source lines, so the step's jaxpr is read: its name stacks are
+    # what becomes each op's op_name
+    batch = S.make_multi_site_batch(_runs())
+    scen, state, *_ = S._prepare_sweep_args(batch, shard=False)
+    row = lambda t: jax.tree.map(lambda x: x[0], t)  # noqa: E731
+    jaxpr = jax.make_jaxpr(S.make_sim_step(batch.hull))(row(scen),
+                                                        row(state))
+    src = Path(S.__file__).read_text().split("\n")
+    first = next(i for i, t in enumerate(src)
+                 if "rsw_healthy = (rsw_timer == 0)" in t) + 1
+    last = next(i for i, t in enumerate(src)
+                if "~jnp.any(csw_usable_f, axis=1))" in t) + 1
+    audit = []
+    for e, names in _eqns(jaxpr.jaxpr):
+        lines = [f.line_num for f in e.source_info.traceback.frames
+                 if f.file_name == S.__file__]
+        if lines and first <= lines[0] <= last:
+            audit.append((e.primitive.name, names))
+    assert {p for p, _ in audit} >= {"reduce_or", "reduce_sum"}
+    for prim, names in audit:
+        assert "faults" in _path(names), (prim, names)
+        assert progtrace.part_of(names) == "gating", (prim, names)
+
+
+@pytest.mark.parametrize("op_name, want", [
+    ("jit(f)/while/body/closed_call/vmap(gating)/faults/eq", True),
+    ("jit(f)/while/body/vmap(gating)/faults/jit(_where)/select_n", True),
+    ("jit(f)/while/body/vmap(gating)/draws/jit(_uniform)/add", False),
+    ("jit(f)/while/body/vmap(gating)/faults/draws/lt", False),
+    ("jit(f)/while/body/vmap(gating)/select_n", False),
+    ("jit(f)/while/body/vmap(stats)/faults_like/add", False),
+    ("", False),
+])
+def test_fault_reader_rule(op_name, want):
+    from chipbench.metrics import faults_us_per_tick
+    assert faults_us_per_tick.holds_faults(op_name) is want
+
+
+def test_fault_reader_on_a_synthetic_record(monkeypatch, tmp_path):
+    """Two devices, each running the chunk program (ops nested in it)
+    and a build program: only the leaves of the chunk program whose
+    op_name holds faults and not draws count, averaged over devices."""
+    from chipbench.metrics import faults_us_per_tick as F
+    names = ["fusion.1", "fusion.2", "fusion.3", "outer"]
+    op_names = {"fusion.1": "jit(c)/while/body/vmap(gating)/faults/eq",
+                "fusion.2": "jit(c)/while/body/vmap(gating)/draws/lt",
+                "fusion.3": "jit(c)/while/body/vmap(rsw)/add",
+                "outer": "jit(c)/while/body/vmap(gating)/faults/while"}
+    # (start, end, name index) in ns: "outer" holds fusion.1 and 2, so
+    # it is no leaf; the fusion.1 at 900 lies in the build program
+    ops = [[0, 100, 3], [10, 40, 0], [40, 60, 1], [100, 130, 2],
+           [130, 150, 0], [900, 950, 0]]
+    mods = [[0, 200, "jit_chunk"], [880, 960, "jit_build"]]
+    dev = {"names": names, "parts": [progtrace.UNSCOPED] * 4, "ops": ops,
+           "modules": mods}
+    slow = dict(dev, ops=[[0, 110, 3], [10, 50, 0], [50, 70, 1],
+                          [110, 140, 2], [140, 170, 0], [900, 950, 0]])
+    rec = {"devices": {"0": dev, "1": slow}, "host": [], "spans": []}
+    # leaves of fusion.1 in the chunk program: 30 + 20 ns on device 0,
+    # 40 + 30 ns on device 1 (the outer op is no leaf)
+    got = F.fault_seconds(rec, {0: op_names, 1: op_names}, 2)
+    assert got == pytest.approx((50 + 70) / 2 / 1e9)
+    assert F.fault_seconds(rec, {0: op_names}, 1) == pytest.approx(50e-9)
+    # a program without the scope reads nothing
+    bare = {0: {k: v.replace("/faults", "") for k, v in op_names.items()}}
+    assert F.fault_seconds(rec, bare, 1) is None
+    # read(): the newest profile's names, over the slice's ticks
+    class Cell:
+        chips = 2
+    prof = tmp_path / "plugins" / "profile" / "x.xplane.pb"
+    prof.parent.mkdir(parents=True)
+    prof.write_bytes(b"")
+    monkeypatch.setattr(progtrace, "record", lambda *a: rec)
+    monkeypatch.setattr(progtrace, "op_names",
+                        lambda path: {0: op_names, 1: op_names})
+    monkeypatch.setattr(F, "newest_profile", lambda: str(prof))
+    monkeypatch.setattr(devtrace, "ticks", lambda trace: 4)
+    ctx = {"cell": Cell(), "trace": {}}
+    assert F.read(ctx) == pytest.approx(1e6 * (50 + 70) / 2 / 1e9 / 4)
 
 
 @pytest.mark.parametrize("name, want", [
